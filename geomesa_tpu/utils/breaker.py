@@ -2,7 +2,7 @@
 
 PR 1's degradation layer made a single device failure cheap (the query
 re-answers from the host scan); this module makes a PERSISTENT failure
-cheap. Without it, a dead device tunnel or unreachable broker pays the
+cheap. Without it, a lost device or unreachable broker pays the
 full dispatch-and-retry cost on every query — each one rediscovering the
 same outage. A ``CircuitBreaker`` remembers:
 

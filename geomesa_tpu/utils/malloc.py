@@ -5,7 +5,7 @@ at ~70-140 MB/s (measured; a normal box does GB/s). Glibc's default
 behavior — mmap for allocations >128 KB, munmap on free, trim the heap
 back to the OS — makes every transient batch buffer re-fault its pages on
 the NEXT batch, which collapsed converter ingest from ~600k to ~277k
-rec/s as RSS grew (NOTES_ROUND3.md "env-level alloc slowdown").
+rec/s as RSS grew (an env-level alloc slowdown seen in round 3).
 
 Measured fix: keep freed memory in the process (M_TRIM_THRESHOLD=max,
 M_MMAP_THRESHOLD=max) so batch N+1 reuses batch N's already-faulted

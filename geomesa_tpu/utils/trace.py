@@ -30,7 +30,7 @@ Usage::
         with trace.span("query", type="gdelt") as root:
             with trace.span("plan"):
                 ...
-            trace.event("degrade.device_to_host", error="tunnel died")
+            trace.event("degrade.device_to_host", error="device lost")
     ring.traces[-1].render()
 
 Cross-process correlation: ``current_trace_id()`` rides in the netlog

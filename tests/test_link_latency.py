@@ -1,5 +1,5 @@
 """Cost gates consult the measured device link latency: a high-latency
-(tunneled/remote) accelerator link makes per-query device dispatch lose to
+(remote) accelerator link makes per-query device dispatch lose to
 the host kernels, so the kNN/density autos must decline there."""
 
 import numpy as np

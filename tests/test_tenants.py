@@ -144,7 +144,7 @@ def test_per_tenant_sums_equal_store_counters():
             by_class[cls] = by_class.get(cls, 0) + c["calls"]
             bad += c["bad"]
     # streams audit through the same "queries" counter as plain queries
-    # (the store's counter taxonomy); the tenant table keeps them as
+    # (the store's counter naming); the tenant table keeps them as
     # their own class, so conservation sums the two
     assert by_class["query"] + by_class.get("stream", 0) == reg.counter(
         "queries")
