@@ -337,9 +337,7 @@ def device_stream(store, queries, wants, n: int, reps: int, cpu_fps: float) -> d
     the batched exact path fuses all queries into one device execution
     per segment. Recorded next to the cost-chosen headline, which may be
     the host seek. A failure here fails the run."""
-    from geomesa_tpu.parallel import executor as exm
-
-    saved = {k: os.environ.get(k) for k in ("GEOMESA_SEEK", "GEOMESA_BATCH_TRACE")}
+    saved = os.environ.get("GEOMESA_SEEK")
     os.environ["GEOMESA_SEEK"] = "0"
     try:
         # warm until the adaptive run capacities stop changing: rcap
@@ -359,19 +357,14 @@ def device_stream(store, queries, wants, n: int, reps: int, cpu_fps: float) -> d
             prev_rcaps = rcaps
         dwarm_s = time.perf_counter() - t0
         log(f"device stream warm (pack+compile): {dwarm_s:.1f}s")
-        # trace the timed stream's batched executions: exec ms, streamed
-        # bytes -> implied HBM GB/s, and the D2H fetch cost
-        os.environ["GEOMESA_BATCH_TRACE"] = "1"
-        exm.BATCH_TRACE.clear()
         t0 = time.perf_counter()
         dres = store.query_many("gdelt", queries)
         dpipe_s = (time.perf_counter() - t0) / reps
     finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        if saved is None:
+            os.environ.pop("GEOMESA_SEEK", None)
+        else:
+            os.environ["GEOMESA_SEEK"] = saved
     dok = all(
         set(r.fids) == {f"f{j}" for j in w} for r, w in zip(dres, wants)
     )
@@ -384,32 +377,6 @@ def device_stream(store, queries, wants, n: int, reps: int, cpu_fps: float) -> d
     }
     if not dok:
         out["error"] = "device_parity_failure"
-    tr = list(exm.BATCH_TRACE)
-    exm.BATCH_TRACE.clear()
-    if not tr:
-        out["error"] = "device_stream_ran_no_device_batch"
-        return out
-    # executions overlap from the host's view (all batches dispatch
-    # before the first resolve) — merge the [t0, t_ready] intervals for
-    # the device busy time
-    busy = 0.0
-    end = -1.0
-    for a, b in sorted((t["t0"], t["t_ready"]) for t in tr):
-        if a > end:
-            busy += b - a
-            end = b
-        elif b > end:
-            busy += b - end
-            end = b
-    scan = sum(t["scan_bytes"] for t in tr)
-    out.update({
-        "device_exec_ms": round(busy * 1000 / len(tr), 3),
-        "link_ms": round(sum(t["link_ms"] for t in tr) / len(tr), 3),
-        "device_scan_bytes": int(scan),
-        "device_d2h_bytes": int(sum(t["out_bytes"] for t in tr)),
-        "device_gbps": round(scan / busy / 1e9, 2) if busy > 0 else 0.0,
-        "device_batches": len(tr),
-    })
     log(
         f"device stream: {n / dpipe_s:,.0f} features/sec "
         f"({dpipe_s * 1000:.1f} ms/query, parity={dok})"

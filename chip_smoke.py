@@ -16,8 +16,9 @@ own entry points (``TpuDataStore`` over ``TpuScanExecutor(default_mesh())``):
 It fails (non-zero exit, no result line) on any parity mismatch, on any
 query the host answered in the device's place (``degrade.device_to_host``),
 on an open device breaker, when the mask mode is not the Pallas one, when
-the device-forced leg ran no device batch, or when the density grid did
-not come from the edition the auto mode chose. The last stdout line is
+a result of the device-forced leg has no ``device-*`` scan path or the
+leg's devstats receipt shows no ``d2h_bytes``, or when the density grid
+did not come from the edition the auto mode chose. The last stdout line is
 ``{"ok": true, "device": {"platform", "kind", "count"}}``.
 
 ``--chips 4`` runs only the multi-chip phase: the same 20M-row stream with
@@ -46,6 +47,7 @@ from geomesa_tpu.schema.featuretype import parse_spec  # noqa: E402
 from geomesa_tpu.store.datastore import TpuDataStore  # noqa: E402
 from geomesa_tpu.utils.audit import robustness_metrics  # noqa: E402
 from geomesa_tpu.utils.config import properties  # noqa: E402
+from geomesa_tpu.utils import devstats  # noqa: E402
 
 SPEC = "dtg:Date,*geom:Point:srid=4326"
 ROWS = 20_000_000  # the headline's size: ~0.6 GB of segment mirror
@@ -121,8 +123,7 @@ def phase_stream(store: TpuDataStore, name: str, x, y, t, fids,
     out = {}
     for leg in legs:
         device = leg == "device"
-        with env(GEOMESA_SEEK="0" if device else "auto",
-                 GEOMESA_BATCH_TRACE="1" if device else "0"):
+        with env(GEOMESA_SEEK="0" if device else "auto"):
             # warm until the adaptive capacities stop changing: a changed
             # capacity keys a fresh compile, which belongs to set-up
             t0 = time.perf_counter()
@@ -134,28 +135,25 @@ def phase_stream(store: TpuDataStore, name: str, x, y, t, fids,
                     break
                 prev = caps
             warm_s = time.perf_counter() - t0
-            executor_mod.BATCH_TRACE.clear()
             t0 = time.perf_counter()
-            results = store.query_many(name, queries)
+            with devstats.collecting() as receipt:
+                results = store.query_many(name, queries)
             per_query_ms = (time.perf_counter() - t0) * 1000.0 / len(queries)
-            trace = list(executor_mod.BATCH_TRACE)
-            executor_mod.BATCH_TRACE.clear()
         for i, (res, want) in enumerate(zip(results, wants)):
             got = np.sort(np.asarray(res.fids, dtype=fids.dtype))
             check(np.array_equal(got, want),
                   f"{leg} leg: query {i} returned {len(got)} rows, "
                   f"brute force {len(want)}")
+        paths = sorted({res.plan.scan_path for res in results})
         if device:
-            check(len(trace) > 0, "device leg ran no device batch")
+            check(all(p.startswith("device-") for p in paths),
+                  f"device leg answered by {paths}")
+            check(receipt.get("d2h_bytes", 0) > 0, "device leg read nothing back")
         out[leg] = {
             "warm_s": warm_s,
             "ms_per_query": per_query_ms,
-            "device_batches": len(trace),
-            # host clock, dispatch to outputs ready / fetch + decode
-            "batch_exec_ms": [b["exec_ms"] for b in trace],
-            "batch_fetch_ms": [b["link_ms"] for b in trace],
-            "scan_bytes": sum(b["scan_bytes"] for b in trace),
-            "d2h_bytes": sum(b["out_bytes"] for b in trace),
+            "scan_paths": paths,
+            "d2h_bytes": receipt.get("d2h_bytes", 0),
             "hits_first_query": int(len(wants[0])),
         }
         say(f"stream_{leg}", json.dumps(out[leg]))

@@ -191,17 +191,20 @@ class QueryCoalescer:
         dl = m.dl
         unregister = dl.on_cancel(m.event.set) if dl is not None else None
         try:
-            while not m.done:
-                if dl is not None and (
-                    dl.is_cancelled or dl.remaining() <= 0.0
-                ):
-                    if m.finish(_ABANDONED):
-                        # counts/attributes via the deadline's own
-                        # raise paths (deadline.cancelled vs .exceeded)
-                        dl.check("batch.coalesce.wait")
-                    break  # leader won the race: outcome is set
-                m.event.wait(None if dl is None else dl.remaining())
-                m.event.clear()
+            # the queue time a member pays: the leader's window, the
+            # group's shared phase and the members executed ahead of it
+            with trace.span("batch.wait"):
+                while not m.done:
+                    if dl is not None and (
+                        dl.is_cancelled or dl.remaining() <= 0.0
+                    ):
+                        if m.finish(_ABANDONED):
+                            # counts/attributes via the deadline's own
+                            # raise paths (deadline.cancelled vs .exceeded)
+                            dl.check("batch.coalesce.wait")
+                        break  # leader won the race: outcome is set
+                    m.event.wait(None if dl is None else dl.remaining())
+                    m.event.clear()
         finally:
             if unregister is not None:
                 unregister()
@@ -212,7 +215,7 @@ class QueryCoalescer:
         """Leader side: gather joiners for the window, then execute the
         group. The leader is itself members[0]."""
         end = time.monotonic() + window_s
-        with self._cond:
+        with trace.span("batch.gather") as sp, self._cond:
             while not g.closed:
                 left = end - time.monotonic()
                 if left <= 0.0:
@@ -222,6 +225,7 @@ class QueryCoalescer:
             if self._open.get(name) is g:
                 del self._open[name]
             members = list(g.members)
+            sp.set_attr("n", len(members))
         try:
             self._execute_group(name, ft, members)
         finally:

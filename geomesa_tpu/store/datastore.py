@@ -2460,7 +2460,12 @@ class TpuDataStore:
             tf = QueryTransforms.parse(ft, query.properties)
             if tf is not None:
                 ft, columns = tf.apply(columns)
-            agg = run_aggregation(ft, query.hints, columns)
+            # the host reducer apart from the column gather above, which
+            # stays in query.assemble's self time
+            kind = next((h for h in AGGREGATION_HINTS if h in query.hints), "")
+            rows = len(columns.get("__fid__", ()))
+            with trace.span("agg.reduce", rows=rows, kind=kind):
+                agg = run_aggregation(ft, query.hints, columns)
             return QueryResult(ft, _empty_columns(ft), plan, agg)
         if (
             isinstance(columns, LazyColumns)

@@ -79,6 +79,7 @@ def devstats_metrics() -> MetricsRegistry:
         device.pad.rows_used_total / rows_padded_total   monotone totals
         device.hbm.live_bytes     sum of jax.live_arrays() nbytes (gauge)
         device.hbm.bytes_in_use / peak_bytes_in_use      backend stats
+        density.band.overflow     density grids the host re-answered (counter)
 
     One shared registry rather than per-store for the same reason as
     robustness_metrics(): the jit caches and the mesh dispatch helpers
@@ -436,6 +437,11 @@ def device_debug() -> Dict[str, Any]:
         # aggregate pyramid cache (ops/pyramid.py): entries/bytes,
         # hit/miss/build/eviction counters, latest pyramid shape
         "agg": agg_block,
+        # density push-down (parallel/executor.density_scan): grids whose
+        # band overflowed its per-shard buffer, so the host answered
+        "density": {
+            "band_overflow": counters.get("density.band.overflow", 0),
+        },
         # cross-query coalescer reach (parallel/batch.py admission
         # groups + parallel/executor.dispatch_coalesced routing): how
         # many groups formed, the pow2 group-size histogram (all-1s

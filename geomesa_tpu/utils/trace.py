@@ -43,6 +43,7 @@ from __future__ import annotations
 import contextvars
 import json
 import logging
+import sys
 import threading
 import time
 import uuid
@@ -223,11 +224,37 @@ _EXPORTERS_LOCK = threading.Lock()
 _log = logging.getLogger("geomesa_tpu.trace")
 
 
+# jax.profiler.TraceAnnotation once resolved; None until jax is loaded,
+# False where it cannot be imported
+_ANNOTATION: Any = None
+
+
+def _annotation():
+    """The profiler's host annotation class, or None. Resolved once jax is
+    loaded: a process that never imported jax has no device trace to
+    share a clock with, and a span must not be what imports it."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION or None
+
+
 class _SpanContext:
     """The live edition of ``span()``: enters a new Span as the current
-    context, exports the tree from the root's __exit__."""
+    context, exports the tree from the root's __exit__.
 
-    __slots__ = ("span", "_token")
+    It also enters a ``jax.profiler.TraceAnnotation`` of the span's name
+    for the span's lifetime, so a ``jax.profiler.trace`` shows every live
+    span on its host thread, on the same clock as the device's XLA ops
+    (no cost beyond the annotation's own when no profiler runs)."""
+
+    __slots__ = ("span", "_token", "_ann")
 
     def __init__(self, name: str, parent: Optional[Span],
                  trace_id: Optional[str], attrs: Dict[str, Any]):
@@ -244,14 +271,22 @@ class _SpanContext:
             parent.children.append(sp)
         self.span = sp
         self._token = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._token = _CURRENT.set(self.span)
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.span.name)
+            self._ann.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         sp = self.span
         sp.finish()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if exc is not None:
             sp.add_event("error", type=type(exc).__name__, message=str(exc))
         if self._token is not None:

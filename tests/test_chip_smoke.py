@@ -59,6 +59,23 @@ def test_stream_parity_mismatch_fails(chip_branches, monkeypatch):
     )
 
 
+def test_device_leg_answered_off_the_device_fails(chip_branches, monkeypatch):
+    """The device leg is checked by its results' scan paths: a stream the
+    host answered fails the smoke even when every answer is right."""
+    import bench
+    from geomesa_tpu.parallel import TpuScanExecutor
+    from geomesa_tpu.store.datastore import TpuDataStore
+
+    monkeypatch.setenv("GEOMESA_PALLAS", "1")
+    monkeypatch.setattr(TpuScanExecutor, "supports", lambda self, table, plan: False)
+    store = TpuDataStore(executor=TpuScanExecutor(default_mesh(jax.devices()[:1])))
+    x, y, t = bench.synthesize(TINY["rows"], 13)
+    fids = chip_smoke.ingest(store, "gdelt", x, y, t)
+    with pytest.raises(chip_smoke.SmokeFailure, match="device leg answered by"):
+        chip_smoke.phase_stream(store, "gdelt", x, y, t, fids, TINY["queries"],
+                                legs=("device",))
+
+
 def test_host_degradation_fails_the_smoke():
     from geomesa_tpu.parallel import TpuScanExecutor
     from geomesa_tpu.store.datastore import TpuDataStore

@@ -174,18 +174,22 @@ def test_default_dispatch_is_shard_extraction_at_multi_device(monkeypatch):
     """VERDICT r4 #6: with NO env overrides, a multi-device mesh must
     dispatch batched scans through the per-shard bitmap edition — no
     full-mask collective (_gathered) anywhere in the default trace."""
+    from geomesa_tpu.utils import trace
+
     for var in ("GEOMESA_BATCH_PROTO", "GEOMESA_SHARD_EXTRACT",
                 "GEOMESA_PALLAS"):
         monkeypatch.delenv(var, raising=False)
-    monkeypatch.setenv("GEOMESA_BATCH_TRACE", "1")
     mesh = default_mesh()
     assert mesh.devices.size > 1  # the conftest 8-device CPU mesh
     assert ex._batch_proto(mesh) == "bitmap"
     assert ex._shard_extract_on(mesh)
     host, tpu = _stores(n=20_000, seed=77)
     cqls = ["bbox(geom, -30, -20, 20, 25)", "bbox(geom, 0, 0, 60, 50)"]
-    ex.BATCH_TRACE.clear()
-    _parity(host, tpu, cqls)
-    kinds = {t["proto"] for t in ex.BATCH_TRACE}
-    ex.BATCH_TRACE.clear()
+    with trace.exporting(trace.InMemoryTraceExporter()) as ring:
+        _parity(host, tpu, cqls)
+    kinds = {
+        sp.attributes["proto"]
+        for root in ring.traces
+        for sp in root.find("device.fetch.shared")
+    }
     assert kinds == {"bitmap_shard"}, kinds
