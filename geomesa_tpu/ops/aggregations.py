@@ -17,6 +17,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from geomesa_tpu.ops.compaction import compact_indices
 from geomesa_tpu.ops.filters import spatial_mask, temporal_mask
 from geomesa_tpu.parallel.mesh import DATA_AXIS, gated
 from geomesa_tpu.utils.devstats import instrumented_jit
@@ -244,7 +245,12 @@ def density_band(x, y, env, width, height, boxes):
 
     Padded boxes are inverted (min > max) and satisfy neither the wide
     nor the strict test; NaN coordinates (null geometries) fail every
-    comparison and are never banded."""
+    comparison and are never banded.
+
+    The boxes are folded one at a time (K is static) so every output is
+    one elementwise expression of the row's x and y: XLA fuses it into
+    its consumers and builds no row-length temporary (an [N, K] test
+    reduced over K puts the f32 margins and per-box flags in HBM)."""
     xmin, ymin, xmax, ymax = env[0], env[1], env[2], env[3]
     dx = (xmax - xmin) / width
     dy = (ymax - ymin) / height
@@ -258,28 +264,27 @@ def density_band(x, y, env, width, height, boxes):
     cell_band = (jnp.abs(tx - jnp.round(tx)) <= ttx) | (
         jnp.abs(ty - jnp.round(ty)) <= tty
     )
-    bx0, by0, bx1, by1 = boxes[:, 0], boxes[:, 1], boxes[:, 2], boxes[:, 3]
-    exk = jnp.maximum(
-        ex[:, None], eps * jnp.maximum(jnp.abs(bx0), jnp.abs(bx1))[None, :]
-    )
-    eyk = jnp.maximum(
-        ey[:, None], eps * jnp.maximum(jnp.abs(by0), jnp.abs(by1))[None, :]
-    )
-    xx, yy = x[:, None], y[:, None]
-    in_wide = (
-        (xx >= bx0[None, :] - exk) & (xx <= bx1[None, :] + exk)
-        & (yy >= by0[None, :] - eyk) & (yy <= by1[None, :] + eyk)
-    )
-    in_strict = (
-        (xx >= bx0[None, :] + exk) & (xx <= bx1[None, :] - exk)
-        & (yy >= by0[None, :] + eyk) & (yy <= by1[None, :] - eyk)
-    )
-    any_strict = jnp.any(in_strict, axis=1)
-    box_band = jnp.any(in_wide & ~in_strict, axis=1) & ~any_strict
+    any_wide = any_strict = any_edge = jnp.zeros(x.shape, jnp.bool_)
+    for k in range(boxes.shape[0]):
+        bx0, by0, bx1, by1 = boxes[k, 0], boxes[k, 1], boxes[k, 2], boxes[k, 3]
+        exk = jnp.maximum(ex, eps * jnp.maximum(jnp.abs(bx0), jnp.abs(bx1)))
+        eyk = jnp.maximum(ey, eps * jnp.maximum(jnp.abs(by0), jnp.abs(by1)))
+        in_wide = (
+            (x >= bx0 - exk) & (x <= bx1 + exk)
+            & (y >= by0 - eyk) & (y <= by1 + eyk)
+        )
+        in_strict = (
+            (x >= bx0 + exk) & (x <= bx1 - exk)
+            & (y >= by0 + eyk) & (y <= by1 - eyk)
+        )
+        any_wide = any_wide | in_wide
+        any_strict = any_strict | in_strict
+        any_edge = any_edge | (in_wide & ~in_strict)
+    box_band = any_edge & ~any_strict
     band = cell_band | box_band
     near = (
         band
-        & jnp.any(in_wide, axis=1)
+        & any_wide
         & (tx >= -ttx) & (tx <= width + ttx)
         & (ty >= -tty) & (ty <= height + tty)
     )
@@ -311,8 +316,8 @@ def make_sharded_density_dual(
     }.get(mode, density_kernel)
 
     def _band_outputs(cand, local_n):
-        cnt = jnp.sum(cand.astype(jnp.int32)).reshape(1)
-        idx = jnp.nonzero(cand, size=band_cap, fill_value=local_n)[0].astype(jnp.int32)
+        idx, cnt = compact_indices(cand, band_cap, local_n)
+        cnt = cnt.reshape(1)
         shard = jax.lax.axis_index(DATA_AXIS).astype(jnp.int32)
         gidx = jnp.where(idx < local_n, idx + shard * local_n, jnp.int32(-1))
         return gidx, cnt

@@ -43,6 +43,7 @@ import numpy as np
 from geomesa_tpu.curve import time_to_binned, zorder
 from geomesa_tpu.curve.binnedtime import TimePeriod, binned_to_time
 from geomesa_tpu.index.planner import QueryPlan
+from geomesa_tpu.ops.compaction import compact_indices
 from geomesa_tpu.ops.filters import (
     bbox_overlap_mask,
     pad_boxes,
@@ -179,7 +180,7 @@ def _gathered(mask, mesh):
     """Wrap a mask body so the extraction ops see a REPLICATED operand.
 
     The run/bitmap extraction ops downstream of every mask (bounded
-    jnp.nonzero, scatter-at, the _span_bounds framing, packbits) lower
+    compaction, scatter-at, the _span_bounds framing, packbits) lower
     pathologically under GSPMD when their operand stays row-sharded:
     measured 7.1 s vs 7 ms for the same bounded-nonzero extraction at
     262k rows on the 8-device CPU mesh — a ~1000x cliff that dominated
@@ -230,10 +231,8 @@ def _mask_runs(m, rcap: int):
     cnt = jnp.sum(m.astype(jnp.int32))
     prev = jnp.concatenate([jnp.zeros((1,), m.dtype), m[:-1]])
     nxt = jnp.concatenate([m[1:], jnp.zeros((1,), m.dtype)])
-    starts_m = m & ~prev
-    nruns = jnp.sum(starts_m.astype(jnp.int32))
-    starts = jnp.nonzero(starts_m, size=rcap, fill_value=m.shape[0])[0]
-    ends = jnp.nonzero(m & ~nxt, size=rcap, fill_value=m.shape[0])[0]
+    starts, nruns = compact_indices(m & ~prev, rcap, m.shape[0])
+    ends, _ = compact_indices(m & ~nxt, rcap, m.shape[0])
     return cnt, nruns, starts, ends
 
 
@@ -780,10 +779,7 @@ def _exact_bitmap_batch_fn(has_time: bool, span_cap: int, q: int, mode: str,
                            mesh, attr=False):
     """Q exact scans -> (headers i32[q,4], bitmaps u8[q, span_cap//8]).
 
-    The TPU-native extraction: NO compaction on device. Size-bounded
-    ``jnp.nonzero`` lowers to a binary search per output slot — measured
-    ~850 ms per 20M-row query on v5e (the gather poison), which dwarfed
-    both the streaming mask (~1 ms) and the link. Here the device only
+    The TPU-native extraction: NO compaction on device. Here the device only
     does streaming-friendly work: the mask, fused iota-select min/max
     reductions for the first/last hit (_span_bounds — no mask reversal),
     a dynamic-slice of the span window, and a bit-pack.
@@ -4117,9 +4113,10 @@ def _batch_proto(mesh=None) -> str:
     """Transfer protocol for batched exact scans.
 
     GEOMESA_BATCH_PROTO: auto | bitmap | runs | runs_packed.
-    auto -> "bitmap" on accelerator backends (size-bounded nonzero is the
-    measured bottleneck there: ~850 ms per 20M-row extraction on v5e vs
-    streaming-only device work for the bitmap) AND on multi-device meshes
+    auto -> "bitmap" on accelerator backends (streaming-only device
+    work; it was chosen when bounded jnp.nonzero cost ~850 ms per 20M-row
+    extraction on v5e, and has not been weighed against compact_indices,
+    ~3 ms at 33.5M rows) AND on multi-device meshes
     of any backend (the bitmap proto is the only one with a per-shard
     extraction edition, so it is the no-collective default at >1
     devices); "runs_packed" on a single-device CPU backend (nonzero is
@@ -6589,7 +6586,7 @@ class TpuScanExecutor:
             mode = "xla"
         from geomesa_tpu.ops.aggregations import DENSITY_BAND_CAP
 
-        # ONE read of the cap: both the compiled nonzero buffer size and
+        # ONE read of the cap: both the compiled band buffer size and
         # the overflow check below must see the same value (a runtime
         # change to the constant re-keys the fns cache instead of
         # silently truncating against a stale compiled buffer)

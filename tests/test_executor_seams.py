@@ -201,6 +201,39 @@ def test_rle_run_expansion_roundtrip():
         assert rows.min() >= 0 and rows.max() < seg.n
 
 
+@pytest.mark.parametrize("extra", [0, 1])
+def test_runs_from_mask_matches_bounded_nonzero_at_capacity(extra):
+    """The fused RLE buffer at n_runs == rcap and rcap + 1 (truncated)
+    equals the one built from bounded jnp.nonzero."""
+    import jax
+    import jax.numpy as jnp
+
+    rcap, n = 64, 5000
+    rng = np.random.default_rng(extra)
+    m = np.zeros(n, bool)
+    starts = np.sort(rng.choice(np.arange(0, n - 10, 10), rcap - 1 + extra, replace=False))
+    for s, ln in zip(starts, rng.integers(1, 9, starts.size)):
+        m[s:s + ln] = True
+    m[-1] = True  # one more run, ending on the last row
+    mask = jnp.asarray(m)
+
+    def via_nonzero(m):
+        prev = jnp.concatenate([jnp.zeros((1,), m.dtype), m[:-1]])
+        nxt = jnp.concatenate([m[1:], jnp.zeros((1,), m.dtype)])
+        st = jnp.nonzero(m & ~prev, size=rcap, fill_value=n)[0]
+        en = jnp.nonzero(m & ~nxt, size=rcap, fill_value=n)[0]
+        head = jnp.stack([jnp.sum(m.astype(jnp.int32)), jnp.sum((m & ~prev).astype(jnp.int32))])
+        return jnp.concatenate([head, st, en - st + 1]).astype(jnp.int32)
+
+    got = np.asarray(jax.jit(lambda a: ex._runs_from_mask(a, rcap))(mask))
+    want = np.asarray(jax.jit(via_nonzero)(mask))
+    assert got[1] == rcap + extra
+    np.testing.assert_array_equal(got, want)
+    cnt, nruns, st, en = jax.jit(lambda a: ex._mask_runs(a, rcap))(mask)
+    assert int(cnt) == m.sum() and int(nruns) == got[1]
+    assert st.dtype == en.dtype == jnp.int32
+
+
 def test_query_many_matches_sequential_queries():
     host, tpu = _pair()
     queries = [

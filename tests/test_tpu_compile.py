@@ -143,11 +143,18 @@ def test_dual_density_pass_compiles_at_8m_rows(topo, compiled_kernels, grid):
     mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
     one = SingleDeviceSharding(topo.devices[0])
     with_time, _ = make_sharded_density_dual(mesh, grid, grid, "pallas")
-    args = _shapes(one, _padded(DENSITY_ROWS),
-                   [jnp.float32] * 2 + [jnp.int32] * 2 + [jnp.bool_])
+    n = _padded(DENSITY_ROWS)
+    args = _shapes(one, n, [jnp.float32] * 2 + [jnp.int32] * 2 + [jnp.bool_])
     args += _small(one, ((K, 4), jnp.float32), ((W, 3), jnp.int32), ((4,), jnp.float32))
     compiled = with_time._jitted.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the band compaction scatters nothing over the rows (bounded
+    # jnp.nonzero's bincount did: a colliding scatter-add of every row)
+    assert "scatter" not in text
+    # and builds no row-length int32 temporary (the cumsum of the mask)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n * 4 + grid * grid * 4
 
 
 def test_shard_batched_exact_z3_compiles_on_4_chips(topo):
