@@ -9,6 +9,7 @@ features never leave HBM; only the [H, W] grid / scalar reductions do.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -230,7 +231,7 @@ def make_sharded_density(mesh, width: int, height: int, mode: str = "xla"):
 # from the device grid and their indices returned for the host to evaluate
 # and bin from its f64 block columns.
 
-DENSITY_BAND_CAP = 8192  # per-shard band-candidate budget (32KB i32 d2h)
+DENSITY_BAND_CAP = 8192  # per-shard band index buffer (32KB i32 d2h)
 _BAND_ULPS = 16.0  # margin over the rigorous f32 quantization+rounding bound
 
 
@@ -300,10 +301,11 @@ def make_sharded_density_dual(
     the device can certify (mask & ~band), ``band_idx`` is the
     [n_shards * band_cap] packed-array indices of band candidates
     (-1 padding), and ``band_count`` the per-shard true candidate counts
-    (count > band_cap means the buffer truncated — the caller must fall
-    back to the host path). The executor certifies the band rows against
-    the plan's post filter on the f64 host columns and adds their f64
-    GridSnap bins, making the final grid exactly host-parity."""
+    (count > band_cap means the buffer truncated — the caller fetches that
+    segment's band whole from ``make_sharded_band_bitmap``). The executor
+    certifies the band rows against the plan's post filter on the f64 host
+    columns and adds their f64 GridSnap bins, making the final grid
+    exactly host-parity."""
     from geomesa_tpu.ops.filters import bbox_mask_f32
     from geomesa_tpu.ops.pallas_kernels import DENSITY_MAX_DIM, density_grid_pallas
 
@@ -374,6 +376,74 @@ def make_sharded_density_dual(
             check=not use_pallas,
         )
     ), mesh)
+    return with_time, no_time
+
+
+# --- band bitmap: the band of a shard that outgrew its index buffer ----------
+#
+# The dual pass's grid is exact for every row outside the band whatever the
+# band's size; only the band's index buffer is bounded. When a shard's band
+# count exceeds it, this program recomputes the same candidate mask and
+# returns it whole as a packed bitmap (n/8 bytes a shard) for the host to
+# decode and certify. The bits are packed down the sublanes of each
+# (8, lanes) row tile: a flat packbits over [n] relayouts to [n/8, 8] with
+# the minor dim padded to 128 lanes (16 bytes a row of HBM), while the tile
+# pack fuses the band test into one reduce with no row-length temporary.
+
+_BITMAP_LANES = 128
+
+
+def _row_tile_lanes(local_n: int) -> int:
+    """Lanes of the row tile ``pack_row_tiles`` packs (local_n % 8 == 0)."""
+    return math.gcd(_BITMAP_LANES, local_n // 8)
+
+
+def pack_row_tiles(m):
+    """[n] bool -> [n/8] uint8: byte b*lanes + l holds rows
+    b*8*lanes + s*lanes + l for s = 0..7, the first row in the high bit
+    (np.packbits' bit order, so the native decode reads it as is)."""
+    lanes = _row_tile_lanes(m.shape[0])
+    t = m.reshape(-1, 8, lanes).astype(jnp.uint8)
+    w = jnp.left_shift(jnp.uint8(1), jnp.arange(7, -1, -1, dtype=jnp.uint8))
+    return jnp.sum(t * w[None, :, None], axis=1, dtype=jnp.uint8).reshape(-1)
+
+
+def row_tile_rows(pos: np.ndarray, local_n: int) -> np.ndarray:
+    """Inverse of ``pack_row_tiles`` over the shards' concatenated bitmaps:
+    set-bit positions (the decode of the packed stream) -> sorted row
+    indices of the [n_shards * local_n] packed array."""
+    lanes = _row_tile_lanes(local_n)
+    shard, p = np.divmod(np.asarray(pos, dtype=np.int64), local_n)
+    block, r = np.divmod(p, 8 * lanes)
+    lane, sub = np.divmod(r, 8)
+    return np.sort(shard * local_n + block * (8 * lanes) + sub * lanes + lane)
+
+
+def make_sharded_band_bitmap(mesh, width: int, height: int):
+    """(with_time, no_time): the dual pass's band candidates (``near &
+    valid`` and the time mask where there is one) as a packed bitmap per
+    shard (``pack_row_tiles``; decode with ``row_tile_rows``). Same inputs
+    as ``make_sharded_density_dual``'s editions; no kernel, no compaction
+    and no collective, so no dispatch gate either."""
+
+    def band_bitmap(x, y, bins, offs, valid, boxes, windows, env):
+        _, near = density_band(x, y, env, width, height, boxes)
+        return pack_row_tiles(near & valid & temporal_mask(bins, offs, windows))
+
+    def band_bitmap_no_time(x, y, valid, boxes, env):
+        _, near = density_band(x, y, env, width, height, boxes)
+        return pack_row_tiles(near & valid)
+
+    from geomesa_tpu.parallel.mesh import shard_map_fn
+
+    d = P(DATA_AXIS)
+    r = P()
+    with_time = instrumented_jit("density_bitmap.time", shard_map_fn(
+        band_bitmap, mesh, in_specs=(d, d, d, d, d, r, r, r), out_specs=d,
+    ))
+    no_time = instrumented_jit("density_bitmap.notime", shard_map_fn(
+        band_bitmap_no_time, mesh, in_specs=(d, d, d, r, r), out_specs=d,
+    ))
     return with_time, no_time
 
 

@@ -4370,7 +4370,7 @@ class TpuScanExecutor:
         # to its table: identity is re-checked on hit and dead entries are
         # evicted (frees the device-resident shards)
         self._cache: Dict[int, Tuple["weakref.ref", DeviceIndex]] = {}
-        self._density_fns: Dict[Tuple[int, int], tuple] = {}
+        self._density_fns: Dict[tuple, tuple] = {}
         # aggregate-pyramid build reductions, one per cell-bits setting
         self._pyramid_fns: Dict[int, Any] = {}
         # circuit breaker over device.dispatch/fetch: a PERSISTENTLY
@@ -6515,9 +6515,11 @@ class TpuScanExecutor:
         rows it can certify in f32 and returns the indices of rows within
         f32 error of a cell boundary or box edge (the band), which the host
         decides from its f64 columns with the plan's full filter + the f64
-        GridSnap — the density analog of the banded-polygon ring. A band
-        overflowing its per-shard buffer (very fine grids over tiny
-        envelopes) falls back to the host path. {"exact": True} still
+        GridSnap — the density analog of the banded-polygon ring. The band
+        comes back as indices while every shard's fits its buffer
+        (``DENSITY_BAND_CAP``); a segment whose band outgrew it keeps its
+        grid and fetches its band whole as a packed bitmap (the ``density``
+        span's ``band``: ``indices`` or ``bitmap``). {"exact": True} still
         forces the host path outright.
 
         GEOMESA_DENSITY_DEVICE: auto (accelerators only, default) | 1 | 0 —
@@ -6613,26 +6615,20 @@ class TpuScanExecutor:
         # the density analog of the banded-polygon ring)
         total: Optional[np.ndarray] = None
         band: List[Tuple[object, np.ndarray]] = []
+        band_layout = "indices"
         # every D2H read below is a device.fetch (span, deadline, fault
         # point, receipt bytes); ``outcome`` says what became of the
-        # device's work: the grid, or a host answer after an overflow
+        # device's work: the grid, or a host answer after an f32 overflow
         with trace.span("density", segments=len(dev.segments)) as sp:
             for seg in dev.segments:
                 if seg.kind == "z3":
-                    grid, gidx, cnt = fns[0](
-                        seg.xf, seg.yf, seg.bins, seg.t_ms, seg.valid, b, w, e
-                    )
+                    args = (seg.xf, seg.yf, seg.bins, seg.t_ms, seg.valid, b, w, e)
+                    grid, gidx, cnt = fns[0](*args)
                 else:
-                    grid, gidx, cnt = fns[1](seg.xf, seg.yf, seg.valid, b, e)
-                n_band = int(np.max(_np_local(cnt)))
-                if n_band > band_cap:
-                    # a shard's band overflowed its index buffer (fine
-                    # grid over a tiny envelope): the host path answers
-                    # exactly rather than shipping a truncated band
-                    devstats_metrics().inc("density.band.overflow")
-                    sp.add_event("density.band_overflow", cnt=n_band, cap=band_cap)
-                    sp.set_attr("outcome", "band_overflow")
-                    return None
+                    args = (seg.xf, seg.yf, seg.valid, b, e)
+                    grid, gidx, cnt = fns[1](*args)
+                cnts = _np_local(cnt)
+                n_band = int(np.max(cnts))
                 g = np.asarray(_np_local(grid), dtype=np.float64)
                 if float(g.max()) >= 2.0 ** 24:
                     # the device grid accumulates in f32, which is exact
@@ -6643,11 +6639,23 @@ class TpuScanExecutor:
                     sp.set_attr("outcome", "f32_overflow")
                     return None
                 total = g if total is None else total + g
-                idx = _np_local(gidx)
-                idx = idx[idx >= 0]
+                if n_band > band_cap:
+                    # a shard's band outgrew its index buffer (dense data
+                    # near the cell edges): the grid stands, exact outside
+                    # the band, and the band comes back whole as a bitmap
+                    devstats_metrics().inc("density.band.overflow")
+                    sp.add_event("density.band_overflow", cnt=n_band, cap=band_cap)
+                    band_layout = "bitmap"
+                    idx = self._density_band_bitmap(
+                        seg, args, width, height, int(np.sum(cnts))
+                    )
+                else:
+                    idx = _np_local(gidx)
+                    idx = idx[idx >= 0]
                 if idx.size:
                     band.append((seg, idx))
             rows = sum(int(idx.size) for _, idx in band)
+            sp.set_attr("band", band_layout)
             sp.set_attr("band_rows", rows)
             if band:
                 with trace.span("density.certify", rows=rows):
@@ -6669,6 +6677,34 @@ class TpuScanExecutor:
             )
             self._density_fns[key] = fns
         return fns
+
+    def _density_band_bitmap(self, seg, args, width: int, height: int,
+                             n_band: int) -> np.ndarray:
+        """One segment's band candidates, fetched whole as a packed bitmap
+        (ops/aggregations.make_sharded_band_bitmap over the dual pass's
+        inputs) and decoded to packed-array row indices. The device copy
+        is dropped as soon as the host holds the bytes."""
+        from geomesa_tpu.ops.aggregations import (
+            make_sharded_band_bitmap,
+            row_tile_rows,
+        )
+
+        key = ("bitmap", width, height)
+        fns = self._density_fns.get(key)
+        if fns is None:
+            fns = self._density_fns[key] = make_sharded_band_bitmap(
+                self.mesh, width, height
+            )
+        with trace.span("density.band_bitmap", rows=n_band):
+            bits = fns[0 if seg.kind == "z3" else 1](*args)
+            packed = _np_local(bits)
+            del bits
+            pos = _decode_bitmap_rows(packed, 0, n_band)
+        if pos.size != n_band:
+            raise ValueError(
+                f"density band bitmap holds {pos.size} rows, the pass counted {n_band}"
+            )
+        return row_tile_rows(pos, seg.shard_n())
 
     def _certify_density_band(
         self, table: IndexTable, plan: QueryPlan, spec,

@@ -79,7 +79,7 @@ def devstats_metrics() -> MetricsRegistry:
         device.pad.rows_used_total / rows_padded_total   monotone totals
         device.hbm.live_bytes     sum of jax.live_arrays() nbytes (gauge)
         device.hbm.bytes_in_use / peak_bytes_in_use      backend stats
-        density.band.overflow     density grids the host re-answered (counter)
+        density.band.overflow     density bands fetched as a bitmap (counter)
 
     One shared registry rather than per-store for the same reason as
     robustness_metrics(): the jit caches and the mesh dispatch helpers
@@ -438,7 +438,7 @@ def device_debug() -> Dict[str, Any]:
         # hit/miss/build/eviction counters, latest pyramid shape
         "agg": agg_block,
         # density push-down (parallel/executor.density_scan): grids whose
-        # band overflowed its per-shard buffer, so the host answered
+        # band overflowed its per-shard buffer and came back as a bitmap
         "density": {
             "band_overflow": counters.get("density.band.overflow", 0),
         },

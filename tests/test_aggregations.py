@@ -312,8 +312,9 @@ def test_density_band_actually_engaged():
 
 
 def test_density_band_overflow_falls_back_to_host(monkeypatch):
-    """A band larger than the per-shard index budget must decline the
-    device path (host answers exactly) instead of truncating."""
+    """A band larger than the per-shard index budget keeps the device
+    grid and fetches the band whole as a bitmap: the grid is exact and the
+    device still answers (no host fallback, no truncated band)."""
     from geomesa_tpu.ops import aggregations as agg
 
     # the cap is read inside density_scan from the aggregations module
@@ -327,6 +328,157 @@ def test_density_band_overflow_falls_back_to_host(monkeypatch):
     q = Query.cql(BOUNDARY_CQL, hints={"density": dict(BOUNDARY_DENSITY)})
     want = host.query("aggb", q).aggregate["density"]
     res = tpu.query("aggb", q)
-    # grid still exact — just via the host reducer fallback
     np.testing.assert_array_equal(res.aggregate["density"], want)
-    assert res.plan.scan_path != "device-density"
+    assert res.plan.scan_path == "device-density"
+
+
+@pytest.mark.parametrize("cap", [1, 16, 8192])
+@pytest.mark.parametrize("edition", ["time", "no_time"])
+def test_density_band_bitmap_grid_exact_across_shards(monkeypatch, cap, edition):
+    """On the 8-device CPU mesh the band straddles every shard: whichever
+    layout the band comes back in (the bitmap below the band's size, the
+    indices at 8192), the grid equals the host's exactly."""
+    from geomesa_tpu.ops import aggregations as agg
+    from geomesa_tpu.utils import trace
+
+    monkeypatch.setattr(agg, "DENSITY_BAND_CAP", cap)
+    cql = BOUNDARY_CQL if edition == "time" else BOUNDARY_CQL.split(" AND ")[0]
+    q = Query.cql(cql, hints={"density": dict(BOUNDARY_DENSITY)})
+    host = TpuDataStore(executor=HostScanExecutor())
+    _fill_boundary(host)
+    mesh = default_mesh()
+    assert mesh.devices.size == 8
+    tpu = TpuDataStore(executor=TpuScanExecutor(mesh))
+    _fill_boundary(tpu)
+    with trace.exporting(trace.InMemoryTraceExporter()) as ring:
+        res = tpu.query("aggb", q)
+    np.testing.assert_array_equal(
+        res.aggregate["density"], host.query("aggb", q).aggregate["density"]
+    )
+    assert res.plan.scan_path == "device-density"
+    (sp,) = ring.traces[-1].find("density")
+    assert sp.attributes["band"] == ("indices" if cap == 8192 else "bitmap")
+    assert sp.attributes["band_rows"] > 8 * cap or cap == 8192
+
+
+def _band_inputs(n, seed=3):
+    """Boundary-heavy rows for the band programs: half on the cell edges
+    of BOUNDARY_DENSITY's grid, a time column of two bins, a ragged tail
+    of invalid rows."""
+    rng = np.random.default_rng(seed)
+    env = np.asarray(BOUNDARY_DENSITY["envelope"], np.float32)
+    w, h = BOUNDARY_DENSITY["width"], BOUNDARY_DENSITY["height"]
+    x = rng.uniform(env[0], env[2], n).astype(np.float32)
+    y = rng.uniform(env[1], env[3], n).astype(np.float32)
+    edge = rng.random(n) < 0.5
+    x[edge] = env[0] + rng.integers(0, w + 1, edge.sum()) * np.float32((env[2] - env[0]) / w)
+    bins = rng.integers(0, 2, n).astype(np.int32)
+    offs = rng.integers(0, 1000, n).astype(np.int32)
+    valid = np.arange(n) < n - n // 7
+    boxes = np.asarray([BOUNDARY_BOX], np.float32)
+    windows = np.asarray([[1, 100, 800]], np.int32)
+    return x, y, bins, offs, valid, boxes, windows, env
+
+
+def _reference_candidates(args, with_time):
+    import jax.numpy as jnp
+
+    from geomesa_tpu.ops.aggregations import density_band
+    from geomesa_tpu.ops.filters import temporal_mask
+
+    x, y, bins, offs, valid, boxes, windows, env = (jnp.asarray(a) for a in args)
+    w, h = BOUNDARY_DENSITY["width"], BOUNDARY_DENSITY["height"]
+    _, near = density_band(x, y, env, w, h, boxes)
+    m = near & valid
+    if with_time:
+        m = m & temporal_mask(bins, offs, windows)
+    return np.flatnonzero(np.asarray(m))
+
+
+def _band_call(fns, args, with_time, mesh):
+    from geomesa_tpu.parallel.mesh import replicate, shard_array
+
+    x, y, bins, offs, valid, boxes, windows, env = args
+    rows = [shard_array(mesh, a) for a in (x, y, bins, offs, valid)]
+    small = [replicate(mesh, a) for a in (boxes, windows, env)]
+    if with_time:
+        return fns[0](*rows, *small)
+    return fns[1](rows[0], rows[1], rows[4], small[0], small[2])
+
+
+@pytest.mark.parametrize("n_devices, rows", [(1, 4096), (1, 64), (8, 8192), (8, 512)])
+@pytest.mark.parametrize("with_time", [True, False])
+def test_band_bitmap_decodes_to_the_reference_mask(n_devices, rows, with_time):
+    """The packed bitmap, decoded as the executor decodes it, is exactly
+    the candidate mask the dual pass compacts: whole (8, 128) row tiles
+    and shards narrower than one."""
+    import jax
+
+    from geomesa_tpu.ops.aggregations import make_sharded_band_bitmap, row_tile_rows
+    from geomesa_tpu.parallel.executor import _decode_bitmap_rows
+
+    mesh = default_mesh(jax.devices()[:n_devices])
+    args = _band_inputs(rows)
+    want = _reference_candidates(args, with_time)
+    assert 0 < want.size < rows
+    fns = make_sharded_band_bitmap(mesh, BOUNDARY_DENSITY["width"], BOUNDARY_DENSITY["height"])
+    packed = np.asarray(_band_call(fns, args, with_time, mesh))
+    assert packed.dtype == np.uint8 and packed.size == rows // 8
+    pos = _decode_bitmap_rows(packed, 0, want.size)
+    np.testing.assert_array_equal(row_tile_rows(pos, rows // n_devices), want)
+
+
+@pytest.mark.parametrize("local_n, shards", [
+    (8, 1), (24, 3), (64, 2), (1024, 1), (1536, 2), (4096, 4), (8192, 1),
+])
+def test_row_tile_pack_round_trips(local_n, shards):
+    """pack_row_tiles per shard, the shards' bytes concatenated, decoded
+    as the executor decodes them: the set rows, in order, for every tile
+    width the shard size allows (gcd(128, local_n / 8) lanes)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomesa_tpu.ops.aggregations import pack_row_tiles, row_tile_rows
+    from geomesa_tpu.parallel.executor import _decode_bitmap_rows
+
+    m = np.random.default_rng(local_n + shards).random(local_n * shards) < 0.3
+    pack = jax.jit(pack_row_tiles)
+    packed = np.concatenate([
+        np.asarray(pack(jnp.asarray(part))) for part in np.split(m, shards)
+    ])
+    assert packed.size == m.size // 8
+    want = np.flatnonzero(m)
+    got = row_tile_rows(_decode_bitmap_rows(packed, 0, want.size), local_n)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("cap", [16, 8192])
+@pytest.mark.parametrize("with_time", [True, False])
+def test_band_bitmap_rows_equal_the_index_set(cap, with_time):
+    """Where the band fits the cap, the bitmap's rows are the dual pass's
+    band indices; where it does not, the indices are its first ``cap``
+    rows a shard and the bitmap holds every counted row."""
+    import jax
+
+    from geomesa_tpu.ops.aggregations import (
+        make_sharded_band_bitmap,
+        make_sharded_density_dual,
+        row_tile_rows,
+    )
+    from geomesa_tpu.parallel.executor import _decode_bitmap_rows
+
+    mesh = default_mesh(jax.devices()[:8])
+    rows = 8192
+    w, h = BOUNDARY_DENSITY["width"], BOUNDARY_DENSITY["height"]
+    args = _band_inputs(rows, seed=9)
+    _, gidx, cnt = _band_call(make_sharded_density_dual(mesh, w, h, "xla", band_cap=cap),
+                              args, with_time, mesh)
+    gidx, cnt = np.asarray(gidx), np.asarray(cnt)
+    pos_bits = np.asarray(_band_call(make_sharded_band_bitmap(mesh, w, h), args, with_time, mesh))
+    got = row_tile_rows(_decode_bitmap_rows(pos_bits, 0, int(cnt.sum())), rows // 8)
+    assert got.size == int(cnt.sum())
+    idx = gidx[gidx >= 0]
+    if int(cnt.max()) <= cap:
+        np.testing.assert_array_equal(got, np.sort(idx))
+    else:
+        assert set(idx.tolist()) < set(got.tolist())

@@ -1,6 +1,7 @@
 """Spans and counters where a heatmap request's time goes: the coalescer's
 queue (``batch.gather``, ``batch.wait``), the density push-down
-(``density``, ``density.certify``, the ``density.band.overflow`` counter),
+(``density``, ``density.certify``, ``density.band_bitmap``, the
+``density.band.overflow`` counter),
 the host reducer (``agg.reduce``), and every live span as a profiler
 annotation on the device trace's clock.
 
@@ -28,12 +29,13 @@ SPEC = "dtg:Date,*geom:Point:srid=4326"
 BASE = int(np.datetime64("2026-01-01T00:00:00", "ms").astype("int64"))
 ENV = (-180.0, -90.0, 180.0, 90.0)
 CQL = "bbox(geom, -150, -60, 150, 60)"
-NEW_SITES = ("batch.gather", "batch.wait", "density", "density.certify", "agg.reduce")
+NEW_SITES = ("batch.gather", "batch.wait", "density", "density.certify", "agg.reduce",
+             "density.band_bitmap")
 
 
-def _density_query(width=360, height=180):
+def _density_query(width=360, height=180, **hint):
     return Query.cql(CQL, hints={"density": {"envelope": ENV, "width": width,
-                                             "height": height}})
+                                             "height": height, **hint}})
 
 
 def _columns(n=6000, seed=5):
@@ -150,6 +152,7 @@ def test_device_grid_span_certifies_the_band(host_grid):
     np.testing.assert_array_equal(res.aggregate["density"], host_grid)
     (sp,) = ring.traces[-1].find("density")
     assert sp.attributes["outcome"] == "grid"
+    assert sp.attributes["band"] == "indices"
     assert sp.attributes["segments"] >= 1
     (cert,) = sp.find("density.certify")
     assert sp.attributes["band_rows"] == cert.attributes["rows"] > 0
@@ -158,6 +161,8 @@ def test_device_grid_span_certifies_the_band(host_grid):
 
 
 def test_band_overflow_counts_and_the_host_answers(host_grid, monkeypatch):
+    """A band past the cap is still counted as an overflow; the device's
+    grid stands and the band comes back as a bitmap to be certified."""
     store = _device_store()
     monkeypatch.setattr(aggregations, "DENSITY_BAND_CAP", 16)
     reg = devstats.devstats_metrics()
@@ -166,30 +171,40 @@ def test_band_overflow_counts_and_the_host_answers(host_grid, monkeypatch):
         res = store.query("pts", _density_query())
     assert reg.counter("density.band.overflow") == before + 1
     assert devstats.device_debug()["density"]["band_overflow"] == before + 1
-    assert res.plan.scan_path != "device-density"
+    assert res.plan.scan_path == "device-density"
     np.testing.assert_array_equal(res.aggregate["density"], host_grid)
     (sp,) = ring.traces[-1].find("density")
-    assert sp.attributes["outcome"] == "band_overflow"
-    assert not sp.find("density.certify")
+    assert sp.attributes["outcome"] == "grid"
+    assert sp.attributes["band"] == "bitmap"
     (ev,) = [e for e in sp.events if e["name"] == "density.band_overflow"]
     assert ev["cap"] == 16 and ev["cnt"] > 16
+    (bm,) = sp.find("density.band_bitmap")
+    assert bm.attributes["rows"] >= ev["cnt"]
+    # the bitmap's read is a device.fetch like every other D2H
+    assert bm.find("device.fetch")
+    (cert,) = sp.find("density.certify")
+    assert cert.attributes["rows"] == sp.attributes["band_rows"] == bm.attributes["rows"]
 
 
 @pytest.mark.parametrize("why", ["band_overflow", "device_off"])
 def test_host_density_reduces_under_assemble(host_grid, monkeypatch, why):
     store = _device_store()
+    hint = {}
     if why == "band_overflow":
-        monkeypatch.setattr(aggregations, "DENSITY_BAND_CAP", 16)
+        # a band overflow no longer reaches the host reducer (the band comes
+        # back as a bitmap); the exact hint is the way there with the device on
+        hint["exact"] = True
     else:
         monkeypatch.setenv("GEOMESA_DENSITY_DEVICE", "0")
     with trace.exporting(trace.InMemoryTraceExporter()) as ring:
-        res = store.query("pts", _density_query())
+        res = store.query("pts", _density_query(**hint))
     np.testing.assert_array_equal(res.aggregate["density"], host_grid)
     root = ring.traces[-1]
     (asm,) = root.find("query.assemble")
     (red,) = [c for c in asm.children if c.name == "agg.reduce"]
     assert red.attributes == {"rows": int(host_grid.sum()), "kind": "density"}
-    assert len(root.find("density")) == (1 if why == "band_overflow" else 0)
+    assert res.plan.scan_path != "device-density"
+    assert not root.find("density")
 
 
 def test_density_fetch_fault_degrades_to_the_host_answer(host_grid):
@@ -228,8 +243,9 @@ def test_query_span_is_a_profiler_annotation(tmp_path):
 @pytest.fixture(scope="module")
 def untraced_sites():
     """What each new site's trace.span returned on a run with no exporter
-    installed: a coalesced group of device grids with a band, and a band
-    overflow answered by the host reducer."""
+    installed: a coalesced group of device grids with a band, a band
+    overflow fetched as a bitmap, and an exact request answered by the host
+    reducer."""
     assert not trace.active()
     got = {}
     real = trace.span
@@ -252,6 +268,7 @@ def untraced_sites():
                     break
             mp.setattr(aggregations, "DENSITY_BAND_CAP", 16)
             store.query("pts", _density_query())
+            store.query("pts", _density_query(exact=True))
     finally:
         mp.undo()
     return got

@@ -171,3 +171,28 @@ def test_shard_batched_exact_z3_compiles_on_4_chips(topo):
     args = _shapes(rows, n, [jnp.uint32] * 6 + [jnp.bool_])
     args += _small(rep, ((q, 8), jnp.uint32), ((q, 4), jnp.uint32))
     fn._jitted.lower(*args).compile()
+
+
+@pytest.mark.parametrize("with_time", [True, False])
+def test_band_bitmap_compiles_at_8m_rows(topo, with_time):
+    """The band bitmap a density request fetches when its band outgrows the
+    index buffer (ops/aggregations.make_sharded_band_bitmap): the band test
+    fused into the tile pack, no scatter, and no row-length mask in HBM
+    (a flat jnp.packbits relayouts the mask to 16 bytes a row)."""
+    from geomesa_tpu.ops.aggregations import make_sharded_band_bitmap
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
+    one = SingleDeviceSharding(topo.devices[0])
+    fns = make_sharded_band_bitmap(mesh, 256, 256)
+    n = _padded(DENSITY_ROWS)
+    if with_time:
+        args = _shapes(one, n, [jnp.float32] * 2 + [jnp.int32] * 2 + [jnp.bool_])
+        args += _small(one, ((K, 4), jnp.float32), ((W, 3), jnp.int32), ((4,), jnp.float32))
+    else:
+        args = _shapes(one, n, [jnp.float32] * 2 + [jnp.bool_])
+        args += _small(one, ((K, 4), jnp.float32), ((4,), jnp.float32))
+    compiled = fns[0 if with_time else 1]._jitted.lower(*args).compile()
+    assert "scatter" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < n // 8 + 2**12
+    assert mem.temp_size_in_bytes < n // 8 + 2**21
