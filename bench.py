@@ -348,7 +348,7 @@ def device_stream(store, queries, wants, n: int, reps: int, cpu_fps: float) -> d
         for _ in range(4):
             store.query_many("gdelt", queries)
             rcaps = {
-                id(s): (s._rcap, s._sum_cap, s._span_cap)
+                id(s): (s._rcap, s._span_cap)
                 for d in store.executor._cache.values()
                 for s in d[1].segments
             }

@@ -93,7 +93,7 @@ def _device_stream_fields(ds, name, cqls, wants, n, base_s):
         for _ in range(3):  # warm until adaptive run capacities settle
             ds.query_many(name, queries)
             caps = {
-                id(s): (s._rcap, s._sum_cap, s._span_cap)
+                id(s): (s._rcap, s._span_cap)
                 for d in getattr(ds.executor, "_cache", {}).values()
                 for s in d[1].segments
             }

@@ -107,7 +107,7 @@ def ingest(store: TpuDataStore, name: str, x, y, t) -> np.ndarray:
 
 def _segment_caps(store: TpuDataStore):
     return {
-        id(s): (s._rcap, s._sum_cap, s._span_cap, s._shard_span_cap)
+        id(s): (s._rcap, s._span_cap, s._shard_span_cap)
         for _ref, dev in store.executor._cache.values()
         for s in dev.segments
     }

@@ -13,6 +13,7 @@ Kernels:
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
@@ -47,22 +48,39 @@ def _host_tag() -> str:
 def _build_so(src: str, so: str) -> bool:
     # lazy JIT compile for THIS host (the host tag in `so` keys the cache):
     # -march=native lets the seek-scan loop vectorize; retry plain -O2 only
-    # for compile errors — a missing g++ or a timeout fails the same way
-    for flags in (["-O3", "-march=native"], ["-O2"]):
-        try:
-            subprocess.run(
-                ["g++", *flags, "-shared", "-fPIC", "-o", so + ".tmp", src],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-            os.replace(so + ".tmp", so)
-            return True
-        except subprocess.CalledProcessError:
-            continue
-        except Exception:
-            return False
-    return False
+    # for compile errors — a missing g++ or a timeout fails the same way.
+    # Concurrent processes may build the same library at once: each
+    # compiles to a temp file of its own and publishes it with an atomic
+    # rename, so no process ever reads or removes another's half-written
+    # output.
+    tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
+    try:
+        for flags in (["-O3", "-march=native"], ["-O2"]):
+            try:
+                subprocess.run(
+                    ["g++", *flags, "-shared", "-fPIC", "-o", tmp, src],
+                    check=True,
+                    capture_output=True,
+                    timeout=120,
+                )
+                os.replace(tmp, so)
+                return True
+            except subprocess.CalledProcessError:
+                continue
+            except Exception:
+                return False
+        return False
+    finally:
+        with contextlib.suppress(OSError):
+            os.unlink(tmp)
+
+
+def _current(src: str, so: str) -> bool:
+    """True when ``so`` exists and is not older than ``src``."""
+    try:
+        return os.path.getmtime(so) >= os.path.getmtime(src)
+    except OSError:
+        return False
 
 
 class _NativeLib:
@@ -88,10 +106,12 @@ class _NativeLib:
                 return self._lib
             self._tried = True
             try:
-                stale = (not os.path.exists(self.so)) or (
-                    os.path.getmtime(self.so) < os.path.getmtime(self.src)
-                )
-                if stale and not _build_so(self.src, self.so):
+                # a failed build still loads a current library that a
+                # concurrent process published in the meantime
+                if not _current(self.src, self.so) and not (
+                    _build_so(self.src, self.so)
+                    or _current(self.src, self.so)
+                ):
                     return None
                 lib = ctypes.CDLL(self.so)
                 fn = getattr(lib, self.symbol)

@@ -63,173 +63,15 @@ def density_kernel(
     return grid.reshape(height, width)
 
 
-_MATMUL_TILE = 16384
-
-
-def density_kernel_matmul(
-    x: jnp.ndarray,
-    y: jnp.ndarray,
-    mask: jnp.ndarray,
-    env: jnp.ndarray,
-    width: int,
-    height: int,
-) -> jnp.ndarray:
-    """The MXU edition of ``density_kernel`` in PLAIN XLA: the grid as a
-    one-hot outer-product matmul, lax.scan'd over static row tiles —
-
-        grid = (W ⊙ onehot_rows)^T @ onehot_cols   per tile, accumulated
-
-    — the same contraction the pallas kernel does
-    (pallas_kernels._density_kernel), but lowered by stock XLA. Scatter-free: on TPU
-    the scatter-add edition serializes through ~n dynamic-update-slices,
-    while this stays dense matmul work. Identical grid by construction —
-    both editions snap through grid_snap_indices."""
-    col, row, in_env = grid_snap_indices(x, y, env, width, height)
-    wgt = jnp.where(mask & in_env, jnp.float32(1.0), jnp.float32(0.0))
-    n = x.shape[0]
-    pad = (-n) % _MATMUL_TILE
-    if pad:
-        col = jnp.pad(col, (0, pad))
-        row = jnp.pad(row, (0, pad))
-        wgt = jnp.pad(wgt, (0, pad))  # zero weight: padding adds nothing
-    nt = (n + pad) // _MATMUL_TILE
-    col = col.reshape(nt, _MATMUL_TILE)
-    row = row.reshape(nt, _MATMUL_TILE)
-    wgt = wgt.reshape(nt, _MATMUL_TILE)
-    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (_MATMUL_TILE, height), 1)
-    cols_iota = jax.lax.broadcasted_iota(jnp.int32, (_MATMUL_TILE, width), 1)
-
-    def step(acc, rcw):
-        r, c, w = rcw
-        # bf16 one-hots (0/1 weights are exact in bf16) with f32
-        # accumulation: the MXU's native input width, ~2x the f32 path
-        r1h = jnp.where(
-            r[:, None] == rows_iota, w[:, None], jnp.float32(0.0)
-        ).astype(jnp.bfloat16)
-        c1h = (c[:, None] == cols_iota).astype(jnp.bfloat16)
-        acc = acc + jax.lax.dot_general(
-            r1h, c1h,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, None
-
-    # the carry must inherit the inputs' varying-manual-axes type: under
-    # shard_map a plain jnp.zeros is unvarying and lax.scan rejects the
-    # carry-in/carry-out mismatch — seed it from a (varying) input value
-    grid0 = jnp.zeros((height, width), jnp.float32) + wgt[0, 0] * 0.0
-    grid, _ = jax.lax.scan(step, grid0, (row, col, wgt))
-    return grid
-
-
-def density_kernel_sort(
-    x: jnp.ndarray,
-    y: jnp.ndarray,
-    mask: jnp.ndarray,
-    env: jnp.ndarray,
-    width: int,
-    height: int,
-) -> jnp.ndarray:
-    """Sort-based edition: flat cell ids sorted once, counts read off as
-    differences of searchsorted boundaries — integer-exact, no scatter,
-    no per-cell FLOPs (the matmul edition pays 2*H*W FLOPs PER ROW; this
-    pays one 32-bit sort + H*W binary searches total). Masked rows sort
-    into a discard bucket past the grid."""
-    col, row, in_env = grid_snap_indices(x, y, env, width, height)
-    hw = height * width
-    flat = jnp.where(mask & in_env, row * width + col, jnp.int32(hw))
-    s = jnp.sort(flat)
-    bounds = jnp.searchsorted(s, jnp.arange(hw + 1, dtype=jnp.int32))
-    return jnp.diff(bounds).astype(jnp.float32).reshape(height, width)
-
-
-def make_sharded_density(mesh, width: int, height: int, mode: str = "xla"):
-    """Build jitted shard_map density passes: per-shard fused exact-predicate
-    mask + scatter, partial grids psum'd over the row axis (the client-merge
-    analog, QueryPlanner.scala:87-92, but on ICI instead of RPC).
-
-    The spatial test runs on raw f32 coords vs raw boxes, the temporal test
-    on raw (bin, offset) windows — exact query semantics, not the coarse
-    int-domain candidate test, so the grid needs no post-filter.
-
-    mode "pallas"/"pallas_spmd" swaps the per-shard inner pass for the MXU
-    one-hot matmul kernel (pallas_kernels.density_grid_pallas) when the
-    grid fits its VMEM budget; "xla_matmul" is the same contraction in
-    plain XLA (density_kernel_matmul — the pallas-free accelerator
-    edition); "xla_sort" counts via one sort + boundary searches
-    (density_kernel_sort); "xla" keeps the scatter-add (the CPU shape).
-    """
-    from geomesa_tpu.ops.filters import bbox_mask_f32
-    from geomesa_tpu.ops.pallas_kernels import DENSITY_MAX_DIM, density_grid_pallas
-
-    use_pallas = mode not in ("xla", "xla_matmul", "xla_sort") and (
-        width <= DENSITY_MAX_DIM and height <= DENSITY_MAX_DIM
-    )
-
-    if use_pallas:
-        def step(x, y, bins, offs, valid, boxes, windows, env):
-            grid = density_grid_pallas(
-                x, y, bins, offs, valid, boxes, windows, env, width, height, True
-            )
-            return jax.lax.psum(grid, DATA_AXIS)
-
-        def step_no_time(x, y, valid, boxes, env):
-            grid = density_grid_pallas(
-                x, y, None, None, valid, boxes, None, env, width, height, False
-            )
-            return jax.lax.psum(grid, DATA_AXIS)
-    else:
-        kern = {
-            "xla_matmul": density_kernel_matmul,
-            "xla_sort": density_kernel_sort,
-        }.get(mode, density_kernel)
-
-        def step(x, y, bins, offs, valid, boxes, windows, env):
-            m = valid & bbox_mask_f32(x, y, boxes) & temporal_mask(bins, offs, windows)
-            return jax.lax.psum(kern(x, y, m, env, width, height), DATA_AXIS)
-
-        def step_no_time(x, y, valid, boxes, env):
-            m = valid & bbox_mask_f32(x, y, boxes)
-            return jax.lax.psum(kern(x, y, m, env, width, height), DATA_AXIS)
-
-    from geomesa_tpu.parallel.mesh import shard_map_fn
-
-    d = P(DATA_AXIS)
-    r = P()
-    # the psum reduction is a REAL collective: gate both editions so
-    # concurrent multi-device executions can never interleave their
-    # rendezvous (parallel/mesh.gated — the PR 9 deadlock fence)
-    with_time = gated(instrumented_jit("density.time", 
-        shard_map_fn(
-            step,
-            mesh,
-            in_specs=(d, d, d, d, d, r, r, r),
-            out_specs=r,
-            check=not use_pallas,
-        )
-    ), mesh)
-    no_time = gated(instrumented_jit("density.notime", 
-        shard_map_fn(
-            step_no_time,
-            mesh,
-            in_specs=(d, d, d, r, r),
-            out_specs=r,
-            check=not use_pallas,
-        )
-    ), mesh)
-    return with_time, no_time
-
-
 # --- exact device density: certain grid + host-certified band ---------------
 #
-# The plain editions bin in f32, so points within f32 error of a grid-cell
+# The device bins in f32, so points within f32 error of a grid-cell
 # boundary or a query-box edge may land differently than the host's f64
-# path (the documented loose-point semantics). The DUAL edition makes the
-# device grid EXACTLY host-parity, reusing the banded-polygon idiom
-# (parallel/executor._poly_mask_body: device decides the bulk, host
-# certifies the ring): rows the device cannot certify in f32 are excluded
-# from the device grid and their indices returned for the host to evaluate
-# and bin from its f64 block columns.
+# path. The DUAL pass makes the device grid EXACTLY host-parity, reusing
+# the banded-polygon idiom (parallel/executor._poly_mask_body: device
+# decides the bulk, host certifies the ring): rows the device cannot
+# certify in f32 are excluded from the device grid and their indices
+# returned for the host to evaluate and bin from its f64 block columns.
 
 DENSITY_BAND_CAP = 8192  # per-shard band index buffer (32KB i32 d2h)
 _BAND_ULPS = 16.0  # margin over the rigorous f32 quantization+rounding bound
@@ -296,10 +138,19 @@ def make_sharded_density_dual(
     mesh, width: int, height: int, mode: str = "xla",
     band_cap: int = DENSITY_BAND_CAP,
 ):
-    """Dual variants of ``make_sharded_density``: each call returns
-    (grid, band_idx, band_count) where the [H, W] grid counts only rows
-    the device can certify (mask & ~band), ``band_idx`` is the
-    [n_shards * band_cap] packed-array indices of band candidates
+    """Jitted shard_map density passes: per-shard fused exact-predicate
+    mask + grid, partial grids psum'd over the row axis (the client-merge
+    analog, QueryPlanner.scala:87-92, but on ICI instead of RPC). The
+    spatial test runs on raw f32 coords vs raw boxes, the temporal test on
+    raw (bin, offset) windows.
+
+    mode "pallas"/"pallas_spmd" runs the per-shard grid as the MXU one-hot
+    matmul kernel (pallas_kernels.density_grid_pallas) when the grid fits
+    its VMEM budget; "xla" keeps the scatter-add (the CPU shape).
+
+    Each call returns (grid, band_idx, band_count) where the [H, W] grid
+    counts only rows the device can certify (mask & ~band), ``band_idx``
+    is the [n_shards * band_cap] packed-array indices of band candidates
     (-1 padding), and ``band_count`` the per-shard true candidate counts
     (count > band_cap means the buffer truncated — the caller fetches that
     segment's band whole from ``make_sharded_band_bitmap``). The executor
@@ -309,13 +160,9 @@ def make_sharded_density_dual(
     from geomesa_tpu.ops.filters import bbox_mask_f32
     from geomesa_tpu.ops.pallas_kernels import DENSITY_MAX_DIM, density_grid_pallas
 
-    use_pallas = mode not in ("xla", "xla_matmul", "xla_sort") and (
+    use_pallas = mode != "xla" and (
         width <= DENSITY_MAX_DIM and height <= DENSITY_MAX_DIM
     )
-    kern = {
-        "xla_matmul": density_kernel_matmul,
-        "xla_sort": density_kernel_sort,
-    }.get(mode, density_kernel)
 
     def _band_outputs(cand, local_n):
         idx, cnt = compact_indices(cand, band_cap, local_n)
@@ -334,7 +181,7 @@ def make_sharded_density_dual(
             )
         else:
             m = valid & bbox_mask_f32(x, y, boxes) & tm
-            grid = kern(x, y, m & ~band, env, width, height)
+            grid = density_kernel(x, y, m & ~band, env, width, height)
         grid = jax.lax.psum(grid, DATA_AXIS)
         gidx, cnt = _band_outputs(near & valid & tm, x.shape[0])
         return grid, gidx, cnt
@@ -348,7 +195,7 @@ def make_sharded_density_dual(
             )
         else:
             m = valid & bbox_mask_f32(x, y, boxes)
-            grid = kern(x, y, m & ~band, env, width, height)
+            grid = density_kernel(x, y, m & ~band, env, width, height)
         grid = jax.lax.psum(grid, DATA_AXIS)
         gidx, cnt = _band_outputs(near & valid, x.shape[0])
         return grid, gidx, cnt
@@ -357,7 +204,9 @@ def make_sharded_density_dual(
 
     d = P(DATA_AXIS)
     r = P()
-    # psum-bearing like the plain editions: same rendezvous fence
+    # the psum reduction is a REAL collective: gate both programs so
+    # concurrent multi-device executions can never interleave their
+    # rendezvous (parallel/mesh.gated)
     with_time = gated(instrumented_jit("density_dual.time", 
         shard_map_fn(
             step,
@@ -462,8 +311,9 @@ def make_pyramid_counts(mesh, bits: int, src_bits: int = 31):
     """Jitted shard_map pyramid-count pass: (xi, yi, mask) -> [H, W] i32
     per-cell row counts, psum'd over the data axis. ``mask`` excludes
     tombstoned and null-geometry rows (their lenient-encoded keys would
-    otherwise count in cell 0). Counting uses the sort + boundary-search
-    idiom (integer-exact, scatter-free — the density_kernel_sort shape)."""
+    otherwise count in cell 0). Counting sorts the flat cell ids once and
+    reads each cell's count off as the difference of its searchsorted
+    boundaries (integer-exact, scatter-free)."""
     n = 1 << bits
     shift = src_bits - bits
 

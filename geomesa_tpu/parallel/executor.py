@@ -77,11 +77,6 @@ MAX_SEGMENTS = 8
 # runs buffers bigger than n/DENSE_BITMAP_FACTOR rows' worth degrade to the
 # packed N/8-byte bitmap (8B/run vs 1bit/row break-even at n/64 runs)
 DENSE_BITMAP_FACTOR = 64
-# packed batch transfer: per-query exception-table capacity (entries whose
-# delta-coded gap or length overflows 16 bits; measured ~1-30 per query on
-# the 20M bench stream) and the initial shared sum-layout capacity
-PACK_XCAP = 256
-SUM_CAP0 = 1 << 17
 
 def _mask_mode(mesh) -> str:
     """Which kernel implementation the executor runs.
@@ -397,8 +392,6 @@ def _bitmap_frame_step(m, span_cap: int):
 
 _EXACT_RUNS_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 _EXACT_PACKED_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
-_EXACT_RUNS_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
-_EXACT_PACKED_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 
 
 def _exact_runs_fn(has_time: bool, rcap: int, mode: str, mesh,
@@ -448,11 +441,10 @@ def _exact_mask_batch_fn(has_time: bool, q: int, mode: str, mesh, attr=False):
     ``q`` is the PADDED query count (pow2 buckets keep jit shapes
     bounded); pad rows repeat the last descriptor and are never decoded.
     ``attr`` threads the rank-code attribute plane exactly like
-    _exact_runs_batch_fn's editions (the coalescer's attr fold). On a
+    _exact_bitmap_batch_fn's editions (the coalescer's attr fold). On a
     multi-device mesh use _exact_shard_mask_batch_fn — the per-shard,
-    collective-free edition — instead; this replicated form stays for
-    single-device meshes (and the GEOMESA_SHARD_EXTRACT=0 A/B posture
-    of the other batch layouts)."""
+    collective-free edition — instead; this replicated form serves
+    single-device meshes."""
     key = (has_time, q, mode, mesh, attr)
     fn = _EXACT_MASK_BATCH_FNS.get(key)
     if fn is None:
@@ -665,113 +657,6 @@ def _start_d2h(*bufs) -> None:
             pass
 
 
-def _exact_runs_batch_fn(has_time: bool, rcap: int, q: int, mode: str, mesh,
-                         attr=False):
-    """Q exact-predicate scans fused into ONE device execution.
-
-    lax.scan over [q] stacked query descriptors; each step streams the
-    whole segment through the exact limb mask and RLE-compresses its hit
-    runs — output [q, 2 + 2*rcap]. One dispatch and one D2H transfer
-    answer the entire query stream, so a high-latency device link pays
-    its per-execution cost once per BATCH instead of once per query.
-    The streaming masks also avoid
-    candidate gathers entirely — on TPU a 2M-row gather from a 20M-row
-    mirror measured ~500 ms while the full 20M-row streaming compare is
-    ~1 ms (HBM-bandwidth bound), so O(N) streaming beats "O(candidates)"
-    random access by orders of magnitude. This is the BatchScanner
-    analog (AccumuloQueryPlan.scala:113-140) collapsed into one RPC.
-    """
-    key = (has_time, rcap, q, mode, mesh, attr)
-    fn = _EXACT_RUNS_BATCH_FNS.get(key)
-    if fn is None:
-        mask = _exact_mask_body(has_time, mode, mesh, attr)
-        mask = _gathered(mask, mesh)
-
-        def run(*args):
-            mask_of, descs = _point_desc_split(mask, has_time, args, attr)
-
-            def step(carry, d):
-                return carry, _runs_from_mask(mask_of(d), rcap)
-
-            _, out = jax.lax.scan(step, 0, descs)
-            return out
-
-        fn = _mesh_gated(instrumented_jit("exact_runs_batch", run), mesh)
-        _EXACT_RUNS_BATCH_FNS[key] = fn
-    return fn
-
-
-def _packed_step(m, rcap: int, sum_cap: int, off, shared):
-    """One query's delta-packed RLE into the shared sum-layout buffer.
-
-    Same starts/ends extraction as _runs_from_mask, then each run becomes
-    ONE u32 word ``(gap & 0xFFFF) << 16 | (len & 0xFFFF)`` where gap is the
-    distance from the previous run's end (first run: from row 0). Entries
-    whose gap or length exceeds 16 bits (rare: the leading skip to the
-    query's first hit, long empty stretches between z-clusters) spill their
-    high bits into a fixed PACK_XCAP exception table carried in the header.
-    Words scatter into ``shared`` at the running offset; out-of-capacity
-    indices drop (the host detects the overflow from the header cumsum and
-    re-fetches those queries singly). Halves the per-run transfer (4B vs
-    8B) AND sizes the buffer by the stream's actual total runs instead of
-    q * rcap — ~21 MB vs ~4 MB of D2H per 20-query stream.
-    """
-    cnt, nruns, starts, ends = _mask_runs(m, rcap)
-    starts = starts.astype(jnp.int32)
-    lens = (ends - starts + 1).astype(jnp.int32)
-    prev_end = jnp.concatenate([jnp.zeros((1,), jnp.int32), (starts + lens)[:-1]])
-    gaps = starts - prev_end
-    slot = jnp.arange(rcap, dtype=jnp.int32)
-    valid = slot < nruns
-    words = ((gaps & 0xFFFF) << 16) | (lens & 0xFFFF)
-    over = valid & ((gaps > 0xFFFF) | (lens > 0xFFFF))
-    nexc = jnp.sum(over.astype(jnp.int32))
-    ex_slot = jnp.nonzero(over, size=PACK_XCAP, fill_value=rcap)[0].astype(jnp.int32)
-    gpad = jnp.concatenate([gaps, jnp.zeros((1,), jnp.int32)])
-    lpad = jnp.concatenate([lens, jnp.zeros((1,), jnp.int32)])
-    ex_gap = (gpad[ex_slot] >> 16).astype(jnp.int32)
-    ex_len = (lpad[ex_slot] >> 16).astype(jnp.int32)
-    tgt = jnp.where(valid, off + slot, sum_cap)
-    shared = shared.at[tgt].set(words, mode="drop")
-    header = jnp.concatenate(
-        [jnp.stack([cnt, nruns, nexc]), ex_slot, ex_gap, ex_len]
-    ).astype(jnp.int32)
-    return off + nruns, shared, header
-
-
-def _exact_packed_batch_fn(has_time: bool, rcap: int, sum_cap: int, q: int,
-                           mode: str, mesh, attr=False):
-    """Q exact scans -> ONE fused i32 buffer
-    ``[q*(3+3*PACK_XCAP) headers | sum_cap shared words]`` (see
-    _packed_step). Same one-execution-per-stream shape as
-    _exact_runs_batch_fn with a ~5x smaller D2H transfer."""
-    key = (has_time, rcap, sum_cap, q, mode, mesh, attr)
-    fn = _EXACT_PACKED_BATCH_FNS.get(key)
-    if fn is None:
-        mask = _exact_mask_body(has_time, mode, mesh, attr)
-        mask = _gathered(mask, mesh)
-
-        def run(*args):
-            mask_of, descs = _point_desc_split(mask, has_time, args, attr)
-            shared0 = jnp.zeros((sum_cap,), jnp.int32)
-
-            def step(carry, d):
-                off, shared = carry
-                off2, shared2, header = _packed_step(
-                    mask_of(d), rcap, sum_cap, off, shared
-                )
-                return (off2, shared2), header
-
-            (_, shared), headers = jax.lax.scan(
-                step, (jnp.int32(0), shared0), descs
-            )
-            return jnp.concatenate([headers.reshape(-1), shared])
-
-        fn = _mesh_gated(instrumented_jit("exact_packed_batch", run), mesh)
-        _EXACT_PACKED_BATCH_FNS[key] = fn
-    return fn
-
-
 _EXACT_BITMAP_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 
 
@@ -788,10 +673,9 @@ def _exact_bitmap_batch_fn(has_time: bool, span_cap: int, q: int, mode: str,
     span_cap is detected host-side (hi - start + 1 > span_cap) and that
     query refetches singly while the segment learns a bigger span bucket.
 
-    On a multi-device mesh the mask is all-gathered to a replicated
-    layout first (_gathered), so the span framing / dynamic-slice /
-    packbits all compile to their single-device form; a future pod
-    deployment could extract per shard and stitch offsets instead.
+    DeviceSegment.dispatch_exact_batch runs it on a single-device mesh;
+    a multi-device mesh frames per shard instead
+    (_exact_shard_bitmap_batch_fn).
     """
     key = (has_time, span_cap, q, mode, mesh, attr)
     fn = _EXACT_BITMAP_BATCH_FNS.get(key)
@@ -814,22 +698,6 @@ def _exact_bitmap_batch_fn(has_time: bool, span_cap: int, q: int, mode: str,
 
 
 _EXACT_SHARD_BITMAP_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
-
-
-def _shard_extract_on(mesh) -> bool:
-    """Per-shard window extraction for the bitmap protocol: ON for ANY
-    multi-device mesh — each chip frames its LOCAL hit window and the
-    host stitches, so the dispatch has no full-mask collective at all;
-    the all-gather (_gathered) remains only for the paths without a
-    shard edition (runs/packed wire formats, single-query fallbacks).
-    GEOMESA_SHARD_EXTRACT=0 forces the gathered extraction everywhere
-    (A/B runs) — the only value with any effect; a single-device mesh
-    always extracts locally regardless."""
-    import os
-
-    if os.environ.get("GEOMESA_SHARD_EXTRACT", "auto") == "0":
-        return False
-    return mesh.devices.size > 1
 
 
 def _exact_shard_bitmap_batch_fn(has_time: bool, span_cap: int, q: int,
@@ -1077,7 +945,7 @@ def _shared_fetch_span(q: int, proto: str):
     batch log (store/datastore.py ``_log_slow_batch``) apportions the
     wait across the members that rode the sweep instead of blaming the
     first member's span tree for all of it. ``proto`` names the batch's
-    wire layout (``bitmap_shard``, ``mask_{kind}``, ``runs_packed``...)."""
+    wire layout (``bitmap``, ``bitmap_shard``, ``mask_{kind}``...)."""
     return trace.span("device.fetch.shared", shared_q=int(q), proto=proto)
 
 
@@ -1336,187 +1204,6 @@ class _PendingBitmapHits:
         return _decode_bitmap_rows(self.batch.query_bits(self.i), start, cnt)
 
 
-def _decode_packed_query(words: np.ndarray, header: np.ndarray, nexc: int):
-    """u32 delta words + exception header row -> (starts, lens) int64."""
-    w = words.view(np.uint32)
-    gaps = (w >> 16).astype(np.int64)
-    lens = (w & 0xFFFF).astype(np.int64)
-    if nexc:
-        slots = header[3 : 3 + nexc].astype(np.int64)
-        gaps[slots] += header[3 + PACK_XCAP : 3 + PACK_XCAP + nexc].astype(np.int64) << 16
-        lens[slots] += (
-            header[3 + 2 * PACK_XCAP : 3 + 2 * PACK_XCAP + nexc].astype(np.int64) << 16
-        )
-    starts = np.cumsum(gaps + np.concatenate([[0], lens[:-1]]))
-    return starts, lens
-
-
-class _PackedBatch:
-    """One packed batch buffer (headers + shared words), fetched once.
-    Exposes per-query header rows and word slices; computes the offset
-    cumsum host-side (the device never materializes offsets).
-
-    On shared-capacity overflow the headers are still complete (only word
-    scatters drop), so the exact required capacity is known — the batch
-    re-dispatches ONCE at that size (``refetch_batch``) instead of paying
-    a single-query round trip per clipped query."""
-
-    __slots__ = ("buf", "q", "q_real", "rcap", "sum_cap", "seg", "_np",
-                 "_offs", "_refetch_batch", "_remembered", "proto")
-
-    def __init__(self, buf, q: int, rcap: int, sum_cap: int, seg=None,
-                 refetch_batch=None, proto="", q_real=None):
-        self.buf = buf
-        self.q = q  # padded query count (device layout)
-        self.q_real = q if q_real is None else q_real
-        self.rcap = rcap
-        self.sum_cap = sum_cap
-        self.seg = seg
-        self._np = None
-        self._offs = None
-        self._refetch_batch = refetch_batch  # sum_cap -> new device buffer
-        self._remembered = False
-        self.proto = proto
-
-    def _fetch(self):
-        if self._np is None:
-            with _shared_fetch_span(self.q_real, self.proto):
-                flat = _np_local(self.buf)
-            self.buf = None
-            hlen = self.q * (3 + 3 * PACK_XCAP)
-            self._np = (flat[:hlen].reshape(self.q, -1), flat[hlen:])
-            nruns = self._np[0][:, 1].astype(np.int64)
-            self._offs = np.concatenate([[0], np.cumsum(nruns)])
-            if self.seg is not None and not self._remembered:
-                # ONCE per batch: the per-query resolves all see the same
-                # stream total, and the gentle-decay hysteresis must step
-                # once per stream, not q times. Learn from the REAL
-                # queries only — the padded duplicate tail repeats the
-                # last descriptor and would overestimate the capacity for
-                # small streams whose last query is run-heavy (the
-                # overflow check below still uses the padded total, which
-                # is what the device actually scattered).
-                self._remembered = True
-                self.seg.remember_entry_total(int(self._offs[self.q_real]))
-        return self._np
-
-    def header(self, i: int) -> np.ndarray:
-        return self._fetch()[0][i]
-
-    def query_words(self, i: int):
-        """Word slice for query i; a shared-buffer overflow re-dispatches
-        the whole batch once at the exact needed capacity (the headers are
-        complete even when word scatters dropped, so the new capacity
-        always fits). Returns None only when re-dispatch is unavailable
-        (the caller then pays a single-query refetch)."""
-        headers, shared = self._fetch()
-        off = int(self._offs[i])
-        nruns = int(headers[i, 1])
-        if off + nruns > self.sum_cap:
-            if self._refetch_batch is None:
-                return None
-            new_cap = _pow2_at_least(int(self.total_entries() * 1.25), SUM_CAP0)
-            buf = self._refetch_batch(new_cap)
-            self.buf = buf
-            self.sum_cap = new_cap
-            self._np = None
-            self._offs = None
-            self._refetch_batch = None  # one escalation per batch
-            return self.query_words(i)
-        return shared[off : off + nruns]
-
-    def total_entries(self) -> int:
-        self._fetch()
-        return int(self._offs[self.q])
-
-
-class _PendingPackedHits:
-    """One query's slice of a packed batch: decodes delta words, falling
-    back to the single-query unpacked refetch on any capacity overflow
-    (per-query rcap, exception table, or shared sum-layout)."""
-
-    __slots__ = ("seg", "batch", "i", "_refetch", "_packed", "_rows")
-
-    def __init__(self, seg: "DeviceSegment", batch: _PackedBatch, i: int,
-                 refetch, packed):
-        self.seg = seg
-        self.batch = batch
-        self.i = i
-        self._refetch = refetch
-        self._packed = packed
-        self._rows: Optional[np.ndarray] = None
-
-    def rows(self) -> np.ndarray:
-        if self._rows is None:
-            self._rows = self._resolve()
-        return self._rows
-
-    def _single_fallback(self, rcap: int) -> np.ndarray:
-        """Unpacked single-query refetch (shared with _PendingHits)."""
-        return _PendingHits(
-            self.seg, rcap, self._refetch(rcap), self._refetch, self._packed
-        ).rows()
-
-    def _resolve(self) -> np.ndarray:
-        seg = self.seg
-        header = self.batch.header(self.i)
-        cnt, nruns, nexc = int(header[0]), int(header[1]), int(header[2])
-        seg.remember_rcap(nruns)
-        if cnt == 0:
-            return np.empty(0, dtype=np.int64)
-        rcap = self.batch.rcap
-        if nruns > rcap:
-            if self._packed is not None and nruns > max(
-                1, seg.n_padded // DENSE_BITMAP_FACTOR
-            ):
-                mask = np.unpackbits(_np_local(self._packed()))[: seg.n].astype(bool)
-                return np.flatnonzero(mask)
-            while rcap < nruns:
-                rcap *= 2
-            return self._single_fallback(rcap)
-        if nexc > PACK_XCAP:
-            return self._single_fallback(rcap)
-        words = self.batch.query_words(self.i)
-        if words is None:  # shared-capacity overflow past this query
-            return self._single_fallback(rcap)
-        starts, lens = _decode_packed_query(words, header, nexc)
-        return _expand_runs(starts, lens)
-
-
-class _BatchRows:
-    """One [q, 2+2*rcap] batch buffer, fetched to host exactly once."""
-
-    __slots__ = ("buf", "_np", "proto")
-
-    def __init__(self, buf, proto=""):
-        self.buf = buf
-        self._np = None
-        self.proto = proto
-
-    def row(self, i: int) -> np.ndarray:
-        if self._np is None:
-            with _shared_fetch_span(self.buf.shape[0], self.proto):
-                self._np = _np_local(self.buf)
-            self.buf = None  # release the device allocation immediately
-        return self._np[i]
-
-
-class _BatchRow:
-    """np.asarray-able view of one query's slice of a _BatchRows buffer
-    (slicing the device array directly would dispatch a device slice op
-    per query — another round trip on the device link)."""
-
-    __slots__ = ("batch", "i")
-
-    def __init__(self, batch: _BatchRows, i: int):
-        self.batch = batch
-        self.i = i
-
-    def __array__(self, dtype=None, copy=None):
-        r = self.batch.row(self.i)
-        return r if dtype is None else r.astype(dtype)
-
-
 def _xz_arg_counts(attr) -> Tuple[int, int]:
     """(row-sharded, replicated) arg counts of the extent mask layouts —
     THE single table for _xz_exact_mask_body's shard specs, the dual
@@ -1637,7 +1324,6 @@ def _xz_desc_split(mask, attr, args):
 
 
 _XZ_RUNS_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
-_XZ_RUNS_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 _XZ_PACKED_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 
 
@@ -1963,7 +1649,6 @@ def _poly_desc_split(mask, attr, args):
 
 
 _POLY_RUNS_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
-_POLY_RUNS_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 _POLY_BITMAP_BATCH_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 _POLY_PACKED_FNS: Dict[tuple, "jax.stages.Wrapped"] = {}
 
@@ -1982,30 +1667,6 @@ def _poly_runs_fn(has_time: bool, rcap: int, mode: str, mesh, attr=False):
 
         fn = _mesh_gated(instrumented_jit("poly_runs", run), mesh)
         _POLY_RUNS_FNS[key] = fn
-    return fn
-
-
-def _poly_runs_batch_fn(has_time: bool, rcap: int, q: int, mode: str, mesh,
-                        attr=False):
-    """Q polygon queries in ONE execution -> [q, 2 x (2 + 2*rcap)]."""
-    key = (has_time, rcap, q, mode, mesh, attr)
-    fn = _POLY_RUNS_BATCH_FNS.get(key)
-    if fn is None:
-        mask = _poly_mask_body(has_time, mode, mesh, attr)
-        mask = _gathered(mask, mesh)
-
-        def run(*args):
-            mask_of, descs = _poly_desc_split(mask, attr, args)
-
-            def step(carry, d):
-                hit, dec = mask_of(d)
-                return carry, _xz_dual_runs(hit, dec, rcap)
-
-            _, out = jax.lax.scan(step, 0, descs)
-            return out
-
-        fn = _mesh_gated(instrumented_jit("poly_runs_batch", run), mesh)
-        _POLY_RUNS_BATCH_FNS[key] = fn
     return fn
 
 
@@ -2065,31 +1726,6 @@ def _xz_runs_fn(has_time: bool, rcap: int, mode: str, mesh, attr=False):
 
         fn = _mesh_gated(instrumented_jit("xz_runs", run), mesh)
         _XZ_RUNS_FNS[key] = fn
-    return fn
-
-
-def _xz_runs_batch_fn(has_time: bool, rcap: int, q: int, mode: str, mesh,
-                      attr=False):
-    """Batched extent edition of _exact_runs_batch_fn: lax.scan over [q]
-    stacked (qbox, window[, qcode]) descriptors -> [q, 2 x (2 + 2*rcap)]."""
-    key = (has_time, rcap, q, mode, mesh, attr)
-    fn = _XZ_RUNS_BATCH_FNS.get(key)
-    if fn is None:
-        mask = _xz_exact_mask_body(has_time, mode, mesh, attr)
-        mask = _gathered(mask, mesh)
-
-        def run(*args):
-            mask_of, descs = _xz_desc_split(mask, attr, args)
-
-            def step(carry, d):
-                hit, decided = mask_of(d)
-                return carry, _xz_dual_runs(hit, decided, rcap)
-
-            _, out = jax.lax.scan(step, 0, descs)
-            return out
-
-        fn = _mesh_gated(instrumented_jit("xz_runs_batch", run), mesh)
-        _XZ_RUNS_BATCH_FNS[key] = fn
     return fn
 
 
@@ -2300,9 +1936,6 @@ class DeviceSegment:
         self.valid = self._pack([self._valid_host], bool, False)
         # adaptive run capacity: grows on overflow, remembered per segment
         self._rcap = HIT_CAPACITY0
-        # packed-batch shared buffer capacity: tracks the observed total
-        # entries of a whole query stream (sum over queries), not q * rcap
-        self._sum_cap = SUM_CAP0
         # bitmap-batch span window (rows): starts at the full segment and
         # narrows to the widest observed query span
         self._span_cap = 0  # 0 = unlearned -> full segment
@@ -2513,17 +2146,6 @@ class DeviceSegment:
             self._shard_span_cap = min(
                 _pow2_at_least(max(int(span), 1), 1 << 13), self.shard_n()
             )
-
-    def remember_entry_total(self, total: int) -> None:
-        """Adapt the packed-batch shared capacity to a stream's observed
-        total entries: grow to the pow2 covering 1.25x the need (headroom
-        for query jitter without a recompile), decay gently. Pow2 buckets
-        bound the number of distinct jit shapes a workload can create."""
-        want = _pow2_at_least(max(int(total * 1.25), 1), SUM_CAP0)
-        if want > self._sum_cap:
-            self._sum_cap = want
-        elif want < self._sum_cap:
-            self._sum_cap = max(want, self._sum_cap // 2)
 
     def dispatch_hits(self, boxes_dev, windows_dev) -> "_PendingHits":
         """Start the device scan WITHOUT blocking: the fused RLE buffer
@@ -3000,8 +2622,8 @@ class DeviceSegment:
         self, descs: Sequence[tuple], has_time: bool,
         attr: Optional[str] = None, attr_kind: str = "member",
     ) -> List["_PendingHits"]:
-        """Q exact scans in ONE device execution (see _exact_runs_batch_fn
-        and _exact_packed_batch_fn).
+        """Q exact scans in ONE device execution (see
+        _exact_bitmap_batch_fn and _exact_shard_bitmap_batch_fn).
 
         ``descs`` = [(box_np u32[8], win_np u32[4]|None)] — or, with
         ``attr`` set, [(box, win, payload)]: the device then also
@@ -3016,19 +2638,15 @@ class DeviceSegment:
         buffer fetch. The query list is padded (repeating the last
         descriptor) so jit shape buckets stay bounded. Overflow
         refetches escalate per query through the single-query path.
-        GEOMESA_BATCH_PROTO (auto|bitmap|runs|runs_packed, see
-        _batch_proto) selects the wire format: span-framed bitmaps on
-        accelerators, delta-packed RLE runs on the CPU backend;
-        GEOMESA_BATCH_PACK=0 degrades runs_packed to the unpacked layout
-        for A/B runs.
+        The wire format follows the mesh: span-framed bitmaps, framed
+        per shard on a multi-device mesh (no collective at all) and over
+        the whole segment on a single device.
         """
         mode = "spmd" if _mask_mode(self.mesh) == "pallas_spmd" else "local"
         q = len(descs)
-        proto = _batch_proto(self.mesh)
         # bitmap rows are span_cap/8 bytes each — pad the query axis to a
-        # multiple of 4 (bounded waste) instead of the pow2 the cheap runs
-        # layouts use
-        qpad = (q + 3) // 4 * 4 if proto == "bitmap" else _pow2_at_least(q, 4)
+        # multiple of 4 (bounded waste)
+        qpad = (q + 3) // 4 * 4
         boxes_np = np.stack(
             [d[0] for d in descs] + [descs[-1][0]] * (qpad - q)
         )
@@ -3050,7 +2668,6 @@ class DeviceSegment:
         args = self._exact_args(
             boxes_dev, wins_dev, has_time, codes_dev, qcodes_dev
         )
-        rcap = self._rcap
 
         def single_args_for(box_np, win_np, values):
             def build():
@@ -3068,90 +2685,39 @@ class DeviceSegment:
                 )
             return build
 
-        def single_fallbacks(single_args):
-            """(refetch, packed) single-query escalation pair — one
-            definition for all three wire-format branches."""
-            refetch = lambda rc, sa=single_args: _exact_runs_fn(  # noqa: E731
-                has_time, rc, mode, self.mesh, is_attr
-            )(*sa())
-            packed = lambda sa=single_args: _exact_packed_fn(  # noqa: E731
-                has_time, mode, self.mesh, is_attr
-            )(*sa())
-            return refetch, packed
-
-        if proto == "bitmap" and _shard_extract_on(self.mesh):
+        if self.mesh.devices.size > 1:
             # per-shard extraction: each chip frames its LOCAL window,
             # the host stitches with shard row offsets — no collectives
-            n_sh = self.mesh.devices.size
             span_cap = self.shard_span_cap()
             hdr, bits = _exact_shard_bitmap_batch_fn(
                 has_time, span_cap, qpad, self.mesh, is_attr
             )(*args)
             _start_d2h(hdr, bits)
             batch = _ShardBitmapBatch(
-                hdr, bits, span_cap, n_sh, qpad, self.shard_n(),
-                seg=self, proto="bitmap_shard",
+                hdr, bits, span_cap, self.mesh.devices.size, qpad,
+                self.shard_n(), seg=self, proto="bitmap_shard",
             )
-            out = []
-            for i, d in enumerate(descs):
-                refetch, packed = single_fallbacks(single_args_for(
-                    d[0], d[1], d[2] if is_attr else None
-                ))
-                out.append(
-                    _PendingShardBitmapHits(self, batch, i, refetch, packed)
-                )
-            return out
-        if proto == "bitmap":
+            pending = _PendingShardBitmapHits
+        else:
             span_cap = self.span_cap()
             hdr, bits = _exact_bitmap_batch_fn(
                 has_time, span_cap, qpad, mode, self.mesh, is_attr
             )(*args)
             _start_d2h(hdr, bits)
             batch = _BitmapBatch(hdr, bits, span_cap, seg=self, proto="bitmap")
-            out = []
-            for i, d in enumerate(descs):
-                refetch, packed = single_fallbacks(single_args_for(
-                    d[0], d[1], d[2] if is_attr else None
-                ))
-                out.append(
-                    _PendingBitmapHits(self, batch, i, refetch, packed)
-                )
-            return out
-        pack = proto == "runs_packed"
-        if pack:
-            sum_cap = self._sum_cap
-            buf = _exact_packed_batch_fn(
-                has_time, rcap, sum_cap, qpad, mode, self.mesh, is_attr
-            )(*args)
-        else:
-            buf = _exact_runs_batch_fn(
-                has_time, rcap, qpad, mode, self.mesh, is_attr
-            )(*args)
-        _start_d2h(buf)
-        if pack:
-            batch = _PackedBatch(
-                buf, qpad, rcap, sum_cap, seg=self,
-                refetch_batch=lambda sc: _exact_packed_batch_fn(
-                    has_time, rcap, sc, qpad, mode, self.mesh, is_attr
-                )(*args),
-                proto=proto,
-                q_real=q,
-            )
-        else:
-            batch = _BatchRows(buf, proto=proto)
+            pending = _PendingBitmapHits
         out = []
         for i, d in enumerate(descs):
             # escalation/bitmap fallbacks re-dispatch the SINGLE-query fns
             # with this query's own descriptor (rare: capacities adapt)
-            refetch, packed = single_fallbacks(single_args_for(
-                d[0], d[1], d[2] if is_attr else None
-            ))
-            if pack:
-                out.append(_PendingPackedHits(self, batch, i, refetch, packed))
-            else:
-                out.append(
-                    _PendingHits(self, rcap, _BatchRow(batch, i), refetch, packed)
-                )
+            sa = single_args_for(d[0], d[1], d[2] if is_attr else None)
+            refetch = lambda rc, sa=sa: _exact_runs_fn(  # noqa: E731
+                has_time, rc, mode, self.mesh, is_attr
+            )(*sa())
+            packed = lambda sa=sa: _exact_packed_fn(  # noqa: E731
+                has_time, mode, self.mesh, is_attr
+            )(*sa())
+            out.append(pending(self, batch, i, refetch, packed))
         return out
 
     def dispatch_exact_mask_batch(
@@ -3163,8 +2729,8 @@ class DeviceSegment:
         escalation (the coalescer's kernel; see _exact_mask_batch_fn).
         ``descs`` = [(box_np u32[8], win_np u32[4]|None)] — or, with
         ``attr`` set, [(box, win, payload)]: the rank-code attribute
-        plane ANDs into the stacked mask exactly like the RLE batch
-        editions (the coalescer's attr fold). Padded to the pow2 query
+        plane ANDs into the stacked mask exactly like the span-framed
+        batch editions (the coalescer's attr fold). Padded to the pow2 query
         bucket by repeating the last descriptor.
 
         On a multi-device mesh the PER-SHARD edition dispatches instead
@@ -3348,9 +2914,7 @@ class DeviceSegment:
         zero edges."""
         mode = "spmd" if _mask_mode(self.mesh) == "pallas_spmd" else "local"
         q = len(descs)
-        proto = _batch_proto(self.mesh)
-        bitmap = proto == "bitmap"
-        qpad = (q + 3) // 4 * 4 if bitmap else _pow2_at_least(q, 4)
+        qpad = (q + 3) // 4 * 4
         ecap = _pow2_at_least(max(len(d[0]) for d in descs), 8)
         padded = descs + [descs[-1]] * (qpad - q)
 
@@ -3374,13 +2938,12 @@ class DeviceSegment:
             replicate(self.mesh, wins_np),
             has_time, codes_dev, qcodes_dev,
         )
-        rcap = self._rcap
-        shard_x = bitmap and _shard_extract_on(self.mesh)
-        if shard_x:
+        if self.mesh.devices.size > 1:
             batch = self._dual_shard_batch(
                 "poly", has_time, qpad, args, attr=is_attr
             )
-        elif bitmap:
+            pending = _PendingDualShardBitmapHits
+        else:
             span_cap = self.span_cap()
             hdr, bits = _poly_bitmap_batch_fn(
                 has_time, span_cap, qpad, mode, self.mesh, is_attr
@@ -3388,12 +2951,7 @@ class DeviceSegment:
             _start_d2h(hdr, bits)
             batch = _BitmapBatch(hdr, bits, span_cap, seg=self,
                                  proto="bitmap_poly")
-        else:
-            buf = _poly_runs_batch_fn(
-                has_time, rcap, qpad, mode, self.mesh, is_attr
-            )(*args)
-            _start_d2h(buf)
-            batch = _BatchRows(buf, proto="runs_poly")
+            pending = _PendingXZBitmapHits
         out = []
         for i, d in enumerate(descs):
             edges, box_np, win_np = d[0], d[1], d[2]
@@ -3422,16 +2980,7 @@ class DeviceSegment:
             packed = lambda sa=single_args: _poly_packed_fn(  # noqa: E731
                 has_time, mode, self.mesh, is_attr
             )(*sa())
-            if shard_x:
-                out.append(
-                    _PendingDualShardBitmapHits(self, batch, i, refetch, packed)
-                )
-            elif bitmap:
-                out.append(_PendingXZBitmapHits(self, batch, i, refetch, packed))
-            else:
-                out.append(
-                    _PendingXZHits(self, rcap, _BatchRow(batch, i), refetch, packed)
-                )
+            out.append(pending(self, batch, i, refetch, packed))
         return out
 
     def _xz_args(
@@ -3514,13 +3063,11 @@ class DeviceSegment:
         [(qbox_np u32[12], win_np u32[4])] — or, with ``attr`` set,
         [(qbox, win, payload)]: the attr test ANDs into the hit plane
         (member literal tuples or range (op, literal) predicate tuples,
-        exactly the point edition's contract). GEOMESA_BATCH_PROTO
-        selects the wire format exactly like the point edition."""
+        exactly the point edition's contract). The wire format follows
+        the mesh exactly like the point edition."""
         mode = "spmd" if _mask_mode(self.mesh) == "pallas_spmd" else "local"
         q = len(descs)
-        proto = _batch_proto(self.mesh)
-        bitmap = proto == "bitmap"
-        qpad = (q + 3) // 4 * 4 if bitmap else _pow2_at_least(q, 4)
+        qpad = (q + 3) // 4 * 4
         boxes_np = np.stack([d[0] for d in descs] + [descs[-1][0]] * (qpad - q))
         wins_np = np.stack([d[1] for d in descs] + [descs[-1][1]] * (qpad - q))
         is_attr, codes_dev, qcodes_dev = self._attr_batch_vectors(
@@ -3531,13 +3078,12 @@ class DeviceSegment:
             replicate(self.mesh, boxes_np), replicate(self.mesh, wins_np),
             has_time, codes_dev, qcodes_dev,
         )
-        rcap = self._rcap
-        shard_x = bitmap and _shard_extract_on(self.mesh)
-        if shard_x:
+        if self.mesh.devices.size > 1:
             batch = self._dual_shard_batch(
                 "xz", has_time, qpad, args, attr=is_attr
             )
-        elif bitmap:
+            pending = _PendingDualShardBitmapHits
+        else:
             span_cap = self.span_cap()
             hdr, bits = _xz_bitmap_batch_fn(
                 has_time, span_cap, qpad, mode, self.mesh, is_attr
@@ -3545,12 +3091,7 @@ class DeviceSegment:
             _start_d2h(hdr, bits)
             batch = _BitmapBatch(hdr, bits, span_cap, seg=self,
                                  proto="bitmap_xz")
-        else:
-            buf = _xz_runs_batch_fn(
-                has_time, rcap, qpad, mode, self.mesh, is_attr
-            )(*args)
-            _start_d2h(buf)
-            batch = _BatchRows(buf, proto="runs_xz")
+            pending = _PendingXZBitmapHits
         out = []
         for i, d in enumerate(descs):
             qbox_np, win_np = d[0], d[1]
@@ -3574,16 +3115,7 @@ class DeviceSegment:
             packed = lambda sa=single_args: _xz_packed_fn(  # noqa: E731
                 has_time, mode, self.mesh, is_attr
             )(*sa())
-            if shard_x:
-                out.append(
-                    _PendingDualShardBitmapHits(self, batch, i, refetch, packed)
-                )
-            elif bitmap:
-                out.append(_PendingXZBitmapHits(self, batch, i, refetch, packed))
-            else:
-                out.append(
-                    _PendingXZHits(self, rcap, _BatchRow(batch, i), refetch, packed)
-                )
+            out.append(pending(self, batch, i, refetch, packed))
         return out
 
     def hit_rows(self, boxes_dev, windows_dev) -> np.ndarray:
@@ -4109,42 +3641,6 @@ def _devseek_fn(has_time: bool, n_iv: int, cand_cap: int, mesh=None):
     return fn
 
 
-def _batch_proto(mesh=None) -> str:
-    """Transfer protocol for batched exact scans.
-
-    GEOMESA_BATCH_PROTO: auto | bitmap | runs | runs_packed.
-    auto -> "bitmap" on accelerator backends (streaming-only device
-    work; it was chosen when bounded jnp.nonzero cost ~850 ms per 20M-row
-    extraction on v5e, and has not been weighed against compact_indices,
-    ~3 ms at 33.5M rows) AND on multi-device meshes
-    of any backend (the bitmap proto is the only one with a per-shard
-    extraction edition, so it is the no-collective default at >1
-    devices); "runs_packed" on a single-device CPU backend (nonzero is
-    cheap host-side and RLE runs are the smallest wire format).
-    GEOMESA_BATCH_PACK=0 degrades runs_packed to the unpacked
-    [q, 2+2*rcap] layout for A/B runs."""
-    import os
-
-    proto = os.environ.get("GEOMESA_BATCH_PROTO", "auto")
-    if proto not in ("auto", "bitmap", "runs", "runs_packed"):
-        import warnings
-
-        warnings.warn(
-            f"unknown GEOMESA_BATCH_PROTO={proto!r}; using auto", stacklevel=2
-        )
-        proto = "auto"
-    if proto == "auto":
-        multi = mesh is not None and getattr(mesh, "devices", np.empty(0)).size > 1
-        proto = (
-            "bitmap"
-            if jax.default_backend() != "cpu" or multi
-            else "runs_packed"
-        )
-    if proto == "runs_packed" and os.environ.get("GEOMESA_BATCH_PACK", "auto") == "0":
-        proto = "runs"
-    return proto
-
-
 def _pow2_at_least(n: int, floor: int = 256) -> int:
     p = floor
     while p < n:
@@ -4459,9 +3955,9 @@ class TpuScanExecutor:
         protocol measured ~500 ms/query on TPU v5e (random 2M-row gathers
         from a 20M-row mirror) while the streaming full-scan exact mask is
         ~1 ms — TPU gathers are not HBM-bandwidth-bound, streaming compares
-        are. The batched exact path (_exact_runs_batch_fn) supersedes this
-        protocol; it stays forceable for parity tests and for hardware
-        where gathers win."""
+        are. The batched exact path (dispatch_exact_batch's span-framed
+        bitmaps) supersedes this protocol; it stays forceable for parity
+        tests and for hardware where gathers win."""
         import os
 
         return os.environ.get("GEOMESA_DEVSEEK", "auto") == "1"
@@ -4942,8 +4438,8 @@ class TpuScanExecutor:
         Plans whose full filter reduces to one exact box(+window) test on
         the same z-index table — after the cost-based seek choice declines
         them — fuse into ONE batched device execution per segment
-        (_exact_runs_batch_fn), so the per-execution link cost of a
-        remote accelerator amortizes across the whole stream.
+        (DeviceSegment.dispatch_exact_batch), so the per-execution link
+        cost of a remote accelerator amortizes across the whole stream.
         Everything else takes the same path dispatch_candidates would.
         """
         out: Dict[int, object] = {}
@@ -5488,16 +4984,14 @@ class TpuScanExecutor:
 
     @staticmethod
     def _seed_spans(dev, plans) -> None:
-        """Plan-derived span seeding for unlearned segments (bitmap proto
-        only): each plan's decomposed z-ranges searchsort into the sorted
-        blocks (the same tiny pass the host-seek cost probe pays), giving
+        """Plan-derived span seeding for unlearned segments: each plan's
+        decomposed z-ranges searchsort into the sorted blocks (the same
+        tiny pass the host-seek cost probe pays), giving
         a conservative candidate row-interval cover per segment; the
         widest planned span across the stream seeds the segment's bitmap
         window so the first device stream never transfers the full
         n_padded/8-byte plane (VERDICT r3 #2 / ADVICE: unlearned
         first-stream cost)."""
-        if not dev.segments or _batch_proto(dev.segments[0].mesh) != "bitmap":
-            return
         for seg in dev.segments:
             if seg._span_cap != 0 or not seg.n:
                 continue
@@ -6564,25 +6058,10 @@ class TpuScanExecutor:
                 # each process could read only its own shards' band
                 return None
         width, height = int(spec["width"]), int(spec["height"])
-        # GEOMESA_DENSITY_KERNEL pins the edition outright; otherwise the
-        # kernel mode tracks the mask mode. A kernel that fails raises:
-        # no edition is swapped in behind the caller's back
-        mode = None
-        pin = os.environ.get("GEOMESA_DENSITY_KERNEL")
-        if pin in ("pallas", "xla", "xla_matmul", "xla_sort"):
-            mode = pin
-        elif pin:
-            import warnings
-
-            warnings.warn(
-                f"unknown GEOMESA_DENSITY_KERNEL={pin!r}; using auto",
-                stacklevel=2,
-            )
-        if mode is None:
-            mode = _mask_mode(self.mesh)
-        if mode not in ("xla", "xla_matmul", "xla_sort") and not all(
-            s._pallas_ok for s in dev.segments
-        ):
+        # the kernel mode tracks the mask mode. A kernel that fails
+        # raises: no edition is swapped in behind the caller's back
+        mode = _mask_mode(self.mesh)
+        if mode != "xla" and not all(s._pallas_ok for s in dev.segments):
             # some segment lacks the per-shard tile granule the pallas
             # kernel needs: decided from the layout, before any dispatch
             mode = "xla"

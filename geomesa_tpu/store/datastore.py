@@ -2927,7 +2927,7 @@ def _scan_label(scan) -> str:
         pending = getattr(scan, "pending", None)
         if pending:
             # wire format suffix: coalesced full-table masks, span-framed
-            # bitmaps, else RLE runs (packed or not)
+            # bitmaps, else the single-query scans' RLE runs
             pname = type(pending[0][1]).__name__
             fmt = (
                 "mask" if "Mask" in pname

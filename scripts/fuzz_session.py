@@ -40,24 +40,16 @@ MODES = [
     {"GEOMESA_SEEK": "auto", "GEOMESA_TPU_NO_NATIVE": "1"},
     {"GEOMESA_SEEK": "auto", "GEOMESA_DEVSEEK": "1"},
     {"GEOMESA_SEEK": "auto", "GEOMESA_EXACT_DEVICE": "1"},
-    # batched exact device scans (query_many fuses exact-shape plans)
+    # batched exact device scans (query_many fuses exact-shape plans into
+    # the span-framed bitmaps the mesh picks)
     {"GEOMESA_SEEK": "0", "GEOMESA_EXACT_DEVICE": "1", "GEOMESA_DEVBATCH": "1"},
-    # the accelerator wire formats, forced on the CPU parity mesh
-    {"GEOMESA_SEEK": "0", "GEOMESA_EXACT_DEVICE": "1", "GEOMESA_DEVBATCH": "1",
-     "GEOMESA_BATCH_PROTO": "bitmap"},
-    {"GEOMESA_SEEK": "0", "GEOMESA_EXACT_DEVICE": "1", "GEOMESA_DEVBATCH": "1",
-     "GEOMESA_BATCH_PROTO": "runs"},
-    # per-shard window extraction (point + dual-plane editions)
-    {"GEOMESA_SEEK": "0", "GEOMESA_EXACT_DEVICE": "1", "GEOMESA_DEVBATCH": "1",
-     "GEOMESA_BATCH_PROTO": "bitmap", "GEOMESA_SHARD_EXTRACT": "1"},
     # device mask-sum counts alongside the batched scans
     {"GEOMESA_SEEK": "0", "GEOMESA_EXACT_DEVICE": "1", "GEOMESA_DEVBATCH": "1",
      "GEOMESA_COUNT_DEVICE": "1"},
 ]
 _MODE_KEYS = (
     "GEOMESA_SEEK", "GEOMESA_TPU_NO_NATIVE", "GEOMESA_DEVSEEK",
-    "GEOMESA_EXACT_DEVICE", "GEOMESA_DEVBATCH", "GEOMESA_BATCH_PROTO",
-    "GEOMESA_SHARD_EXTRACT", "GEOMESA_COUNT_DEVICE",
+    "GEOMESA_EXACT_DEVICE", "GEOMESA_DEVBATCH", "GEOMESA_COUNT_DEVICE",
 )
 
 

@@ -12,7 +12,7 @@ instead of guesses:
   cumsum       prefix sum (scatter-compaction alternative)
   d2h_4m/h2d_4m  link bandwidth on a 4 MB buffer
   exec_floor   empty-ish execution round trip
-  batch_*      end-to-end _exact_{runs,packed,bitmap}_batch_fn, q=20
+  batch_bitmap end-to-end _exact_bitmap_batch_fn, q=20
 
 Writes chiprun_out/HW_PRIMS.json and prints one JSON line. Refuses to
 run without a TPU.
@@ -138,18 +138,6 @@ def main():
     xh, xl, yh, yl = limb(1), limb(0), limb(1), limb(0)
     valid = jax.device_put(np.ones(N, bool))
     boxes = replicate(mesh, rng.integers(0, 2**31, (Q, 8)).astype(np.uint32))
-
-    runs_fn = ex._exact_runs_batch_fn(False, RCAP, Q, mode, mesh)
-    out["batch_runs_ms"] = median3(
-        lambda: np.asarray(runs_fn(xh, xl, yh, yl, valid, boxes))
-    ) * 1e3
-    flush(out)
-
-    packed_fn = ex._exact_packed_batch_fn(False, RCAP, 1 << 20, Q, mode, mesh)
-    out["batch_packed_ms"] = median3(
-        lambda: np.asarray(packed_fn(xh, xl, yh, yl, valid, boxes))
-    ) * 1e3
-    flush(out)
 
     span = 1 << 23  # 8M-row window (1 MB bitmap/query)
     bm_fn = ex._exact_bitmap_batch_fn(False, min(span, N - N % 8), Q, mode, mesh)

@@ -171,50 +171,6 @@ def test_device_density_exact_exclusive_bounds(host_store, tpu_store):
     np.testing.assert_allclose(got, want)
 
 
-def test_density_matmul_edition_matches_scatter():
-    """density_kernel_matmul (the pallas-free MXU contraction) must
-    produce the identical grid as the scatter-add edition — both snap
-    through grid_snap_indices, so equality is exact, including the
-    sub-tile padding path."""
-    import jax.numpy as jnp
-
-    from geomesa_tpu.ops.aggregations import (
-        density_kernel,
-        density_kernel_matmul,
-    )
-
-    rng = np.random.default_rng(21)
-    for n in (100, 8192, 20000):
-        x = jnp.asarray(rng.uniform(-30, 30, n), jnp.float32)
-        y = jnp.asarray(rng.uniform(-30, 30, n), jnp.float32)
-        mask = jnp.asarray(rng.random(n) < 0.7)
-        env = jnp.asarray([-20.0, -20.0, 20.0, 20.0], jnp.float32)
-        a = np.asarray(density_kernel(x, y, mask, env, 32, 16))
-        b = np.asarray(density_kernel_matmul(x, y, mask, env, 32, 16))
-        np.testing.assert_array_equal(a, b)
-
-
-def test_density_sort_edition_matches_scatter():
-    """density_kernel_sort (sort + boundary searches) must equal the
-    scatter edition exactly — integer counting, no float paths."""
-    import jax.numpy as jnp
-
-    from geomesa_tpu.ops.aggregations import (
-        density_kernel,
-        density_kernel_sort,
-    )
-
-    rng = np.random.default_rng(31)
-    for n in (100, 5000, 40000):
-        x = jnp.asarray(rng.uniform(-30, 30, n), jnp.float32)
-        y = jnp.asarray(rng.uniform(-30, 30, n), jnp.float32)
-        mask = jnp.asarray(rng.random(n) < 0.6)
-        env = jnp.asarray([-20.0, -20.0, 20.0, 20.0], jnp.float32)
-        a = np.asarray(density_kernel(x, y, mask, env, 32, 16))
-        b = np.asarray(density_kernel_sort(x, y, mask, env, 32, 16))
-        np.testing.assert_array_equal(a, b)
-
-
 def _fill_boundary(store, seed=23):
     """Adversarial density data: points engineered within f32 error of
     density-cell boundaries and query-box edges — the rows the dual

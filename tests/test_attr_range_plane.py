@@ -16,6 +16,7 @@ Reference role: the join attribute strategy evaluated at the data
 scans its attribute index serves host-side.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -35,7 +36,8 @@ def _force_batch(monkeypatch):
     monkeypatch.setenv("GEOMESA_SEEK", "0")
 
 
-def _stores(n=30_000, seed=33, batches=3, null_every=13, nan_every=17):
+def _stores(n=30_000, seed=33, batches=3, null_every=13, nan_every=17,
+            devices=None):
     """Multi-batch writes -> multiple blocks whose value pools differ
     (the unified re-encode across mixed dict/raw layouts is the
     correctness risk). ``tag`` is per-row-unique so blocks store the
@@ -61,7 +63,9 @@ def _stores(n=30_000, seed=33, batches=3, null_every=13, nan_every=17):
             Point(float(x[i]), float(y[i])),
         ])
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     for s in (host, tpu):
         s.create_schema(parse_spec("t", SPEC))
         for b in range(batches):
@@ -94,10 +98,9 @@ BOX = "bbox(geom, -100, -60, 80, 60)"
 BOX2 = "bbox(geom, -60, -40, 40, 30)"
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_numeric_equality_and_in_list(monkeypatch, proto):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+@pytest.mark.parametrize("devices", [1, 8])
+def test_numeric_equality_and_in_list(devices):
+    host, tpu = _stores(devices=devices)
     _parity(host, tpu, [
         f"cnt = 5 AND {BOX}",
         f"cnt = 0 AND {BOX2}",
@@ -109,10 +112,9 @@ def test_numeric_equality_and_in_list(monkeypatch, proto):
     _plane_loaded(tpu, "z2", "score")
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_numeric_ranges(monkeypatch, proto):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+@pytest.mark.parametrize("devices", [1, 8])
+def test_numeric_ranges(devices):
+    host, tpu = _stores(devices=devices)
     _parity(host, tpu, [
         f"score > 0.2 AND score <= 0.8 AND {BOX}",
         f"score < 0.5 AND {BOX2}",
@@ -126,8 +128,7 @@ def test_numeric_ranges(monkeypatch, proto):
     _plane_loaded(tpu, "z2", "cnt")
 
 
-def test_string_ranges_dict_and_highcard(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_string_ranges_dict_and_highcard():
     host, tpu = _stores()
     _parity(host, tpu, [
         f"kind >= 'k1' AND kind < 'k3' AND {BOX}",
@@ -142,11 +143,10 @@ def test_string_ranges_dict_and_highcard(monkeypatch):
     _plane_loaded(tpu, "z2", "tag")
 
 
-def test_date_attr_ranges(monkeypatch):
+def test_date_attr_ranges():
     """Secondary date attribute: Cmp coercion + the exclusive temporal
     forms ride the interval edition (the default dtg keeps the window
     plane)."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores()
     _parity(host, tpu, [
         f"seen AFTER 2026-01-20T00:00:00Z AND {BOX}",
@@ -158,8 +158,7 @@ def test_date_attr_ranges(monkeypatch):
     _plane_loaded(tpu, "z2", "seen")
 
 
-def test_range_with_z3_window(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_range_with_z3_window():
     host, tpu = _stores()
     _parity(host, tpu, [
         f"score > 0.3 AND score < 0.9 AND {BOX} AND "
@@ -198,11 +197,10 @@ def test_range_after_delete():
     _parity(host, tpu, [f"cnt BETWEEN 2 AND 8 AND {BOX}"])
 
 
-def test_mixed_member_and_range_stream(monkeypatch):
+def test_mixed_member_and_range_stream():
     """One query_many stream mixing member-kind (equality/IN) and
     range-kind plans: they group into separate batches over the same
     codes column and both stay device-exact."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores()
     _parity(host, tpu, [
         f"cnt = 5 AND {BOX}",
@@ -214,11 +212,10 @@ def test_mixed_member_and_range_stream(monkeypatch):
     _plane_loaded(tpu, "z2", "cnt")
 
 
-def test_like_prefix_rides_code_range(monkeypatch):
+def test_like_prefix_rides_code_range():
     """Single-trailing-% LIKE = a prefix interval on the sorted value
     space; wildcard-free LIKE = equality; both device-decided. Dict and
     high-cardinality string layouts."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores()
     got = _parity(host, tpu, [
         f"kind LIKE 'k1%' AND {BOX}",
@@ -244,10 +241,9 @@ def test_like_non_prefix_falls_back():
     ])
 
 
-def test_is_null_and_not_null_on_device(monkeypatch):
+def test_is_null_and_not_null_on_device():
     """IS NULL = the [-1, -1] code interval (nulls AND float NaN rank
     -1, matching the oracle's ~valid); IS NOT NULL = [0, U-1]."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores(null_every=5, nan_every=7)
     got = _parity(host, tpu, [
         f"kind IS NULL AND {BOX}",

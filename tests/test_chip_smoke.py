@@ -27,7 +27,6 @@ def chip_branches(monkeypatch):
         "GEOMESA_DEVBATCH": "1",
         "GEOMESA_EXACT_DEVICE": "1",
         "GEOMESA_DENSITY_DEVICE": "1",
-        "GEOMESA_BATCH_PROTO": "bitmap",
     }.items():
         monkeypatch.setenv(k, v)
 

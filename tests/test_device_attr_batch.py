@@ -4,6 +4,7 @@ via unified dictionary codes, fused into the same batched exact scans
 as the box(+window) test (AttributeIndex.scala:42,392 role).
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -23,9 +24,10 @@ def _force_batch(monkeypatch):
     monkeypatch.setenv("GEOMESA_SEEK", "0")
 
 
-def _stores(n=40_000, seed=21, batches=3, null_every=11):
+def _stores(n=40_000, seed=21, batches=3, null_every=11, devices=None):
     """Multiple write batches -> multiple blocks with DISTINCT per-block
-    vocabs (the unified re-encode is the correctness risk)."""
+    vocabs (the unified re-encode is the correctness risk). ``devices``
+    cuts the mesh to its first N devices (the wire format follows it)."""
     rng = np.random.default_rng(seed)
     x = rng.uniform(-170, 170, n)
     y = rng.uniform(-80, 80, n)
@@ -39,7 +41,9 @@ def _stores(n=40_000, seed=21, batches=3, null_every=11):
     kinds[::null_every] = None
     fids = np.array([f"f{i}" for i in range(n)], dtype=object)
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     for s in (host, tpu):
         s.create_schema(parse_spec("t", SPEC))
         # one columnar write per batch keeps the multiple-blocks /
@@ -77,10 +81,11 @@ CQLS_Z3 = [
 ]
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed", "runs"])
-def test_attr_batch_parity_all_protocols(monkeypatch, proto):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+@pytest.mark.parametrize("devices", [1, 2, 8])
+def test_attr_batch_parity_all_protocols(devices):
+    """One device frames the span bitmap over the whole segment; two and
+    eight frame it per shard."""
+    host, tpu = _stores(devices=devices)
     _parity(host, tpu, CQLS_Z2)
     # the device attr plane actually ran: unified code columns exist
     table = tpu._tables["t"]["z2"]
@@ -91,8 +96,7 @@ def test_attr_batch_parity_all_protocols(monkeypatch, proto):
     )
 
 
-def test_attr_batch_parity_with_time(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_attr_batch_parity_with_time():
     host, tpu = _stores()
     _parity(host, tpu, CQLS_Z3)
     table = tpu._tables["t"]["z3"]
@@ -117,13 +121,12 @@ def test_attr_batch_after_delete():
     _parity(host, tpu, CQLS_Z2[:2])
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_attr_in_list_parity(monkeypatch, proto):
+@pytest.mark.parametrize("devices", [1, 8])
+def test_attr_in_list_parity(devices):
     """attr IN (...) rides the membership plane: K-padded qcode vectors
     (equality is the K=1 case), mixed list sizes in one stream, absent
     members, duplicates deduped."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+    host, tpu = _stores(devices=devices)
     cqls = [
         "kind IN ('k0', 'k2') AND bbox(geom, -60, -40, 40, 30)",
         "kind IN ('k1', 'k3', 'k4', 'nope') AND bbox(geom, -100, -60, 80, 60)",

@@ -1,5 +1,5 @@
 """Batched exact device scans: query_many fuses many exact-shape plans
-into ONE device execution per segment (_exact_runs_batch_fn). Results must
+into ONE device execution per segment (dispatch_exact_batch). Results must
 match per-query host execution bit-for-bit, and the batch must actually
 take the fused path (one batch dispatch, not Q singles)."""
 
@@ -79,11 +79,9 @@ def test_batched_query_many_parity_time():
     rng = np.random.default_rng(3)
     cqls = _cqls(rng, 12, with_time=True)
     calls = {"batch": 0}
-    # spy on every batch-kernel builder: which one runs depends on the
-    # default wire format (runs_packed single-device CPU; per-shard
-    # bitmap on multi-device meshes)
-    spied = ("_exact_runs_batch_fn", "_exact_packed_batch_fn",
-             "_exact_bitmap_batch_fn", "_exact_shard_bitmap_batch_fn")
+    # spy on both batch-kernel factories: the wire format follows the mesh
+    # (whole-segment bitmap on one device, per-shard bitmap on several)
+    spied = ("_exact_bitmap_batch_fn", "_exact_shard_bitmap_batch_fn")
     origs = {name: getattr(ex, name) for name in spied}
 
     def counting(orig):

@@ -1,7 +1,8 @@
-"""Batched exact EXTENT device scans (xz2/xz3): dual RLE buffers (hit +
-decided runs) per query in one execution; the boundary ring takes the
+"""Batched exact EXTENT device scans (xz2/xz3): dual span-framed bitmaps
+(hit + decided planes) per query in one execution; the boundary ring takes the
 host's per-geometry test. Results must match per-query host execution."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,9 +21,11 @@ def _force_batch(monkeypatch):
     monkeypatch.setenv("GEOMESA_SEEK", "0")
 
 
-def _pair(n=1200, seed=31):
+def _pair(n=1200, seed=31, devices=None):
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     for s in (host, tpu):
         s.create_schema(parse_spec("e", "dtg:Date,*geom:Geometry:srid=4326"))
     rng = np.random.default_rng(seed)
@@ -84,10 +87,9 @@ def test_xz2_batched_parity():
     rng = np.random.default_rng(1)
     cqls = _queries(rng, 10, time_frac=0.0)
     calls = {"n": 0}
-    # spy every xz batch-kernel builder: the wire format (runs vs
-    # bitmap/shard) depends on the mesh-aware default proto
-    spied = ("_xz_runs_batch_fn", "_xz_bitmap_batch_fn",
-             "_dual_shard_bitmap_batch_fn")
+    # spy both xz batch-kernel factories: the wire format follows the mesh
+    # (whole-segment bitmap on one device, per-shard bitmap on several)
+    spied = ("_xz_bitmap_batch_fn", "_dual_shard_bitmap_batch_fn")
     origs = {name: getattr(ex, name) for name in spied}
 
     def counting(orig):
@@ -154,12 +156,10 @@ def test_xz_batch_respects_deletes():
         assert not set(map(str, res.fids)) & set(doomed)
 
 
-def test_xz_bitmap_protocol_parity(monkeypatch):
-    """The span-framed dual-bitmap wire format (GEOMESA_BATCH_PROTO=bitmap)
-    must produce identical results, including the ring rows that take the
-    host's per-geometry test, across two streams (second rides the learned
-    span window)."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_xz_bitmap_protocol_parity():
+    """The span-framed dual-bitmap wire format must produce identical
+    results, including the ring rows that take the host's per-geometry
+    test, across two streams (second rides the learned span window)."""
     host, tpu = _pair(seed=41)
     rng = np.random.default_rng(6)
     cqls = _queries(rng, 5, time_frac=0.0, poly_frac=0.5) + _queries(rng, 4, time_frac=1.0)
@@ -169,9 +169,10 @@ def test_xz_bitmap_protocol_parity(monkeypatch):
             assert _fids(res) == _fids(host.query("e", cql)), cql
 
 
-def test_xz_bitmap_span_overflow_falls_back(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
-    host, tpu = _pair(seed=43)
+def test_xz_bitmap_span_overflow_falls_back():
+    """A crushed span window on one device (where the bitmap is framed
+    over the whole segment) falls back per query, exactly."""
+    host, tpu = _pair(seed=43, devices=1)
     rng = np.random.default_rng(7)
     cqls = _queries(rng, 5, time_frac=0.3)
     tpu.query_many("e", cqls)  # build mirror

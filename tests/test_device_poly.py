@@ -4,6 +4,7 @@ f32 cast can't certify (the band near edges/vertices) take the host's
 exact test. Results must match per-query host execution bit-for-bit,
 including points placed exactly ON edges and vertices."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -23,9 +24,11 @@ def _force_batch(monkeypatch):
     monkeypatch.setenv("GEOMESA_SEEK", "0")
 
 
-def _stores(x, y, t):
+def _stores(x, y, t, devices=None):
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     for s in (host, tpu):
         s.create_schema(parse_spec("t", "dtg:Date,*geom:Point:srid=4326"))
         with s.writer("t") as w:
@@ -112,8 +115,7 @@ def test_polygon_boundary_points():
     _parity(host, tpu, cqls)
 
 
-def test_polygon_bitmap_protocol(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_polygon_bitmap_protocol():
     rng = np.random.default_rng(4)
     n = 20_000
     x = rng.uniform(-70, 70, n)
@@ -200,14 +202,15 @@ def test_polygon_overflow_escalates_per_query():
     _parity(host, tpu, cqls)
 
 
-def test_polygon_bitmap_span_overflow(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
+def test_polygon_bitmap_span_overflow():
+    """A crushed span window on one device (where the bitmap is framed
+    over the whole segment) falls back per query, exactly."""
     rng = np.random.default_rng(9)
     n = 12_000
     x = rng.uniform(-70, 70, n)
     y = rng.uniform(-70, 70, n)
     t = BASE + rng.integers(0, 86400_000, n)
-    host, tpu = _stores(x, y, t)
+    host, tpu = _stores(x, y, t, devices=1)
     cqls = [f"intersects(geom, {g})" for g in (CONCAVE, HOLED, TRIANGLE, MULTI)]
     tpu.query_many("t", cqls)
     table = tpu._tables["t"]["z2"]

@@ -173,3 +173,50 @@ def test_bitmap_rows_native_matches_numpy():
 
     with pytest.raises(ValueError, match="corrupt bitmap"):
         bitmap_rows_native(np.packbits(bits), 0, 63)
+
+
+_BUILD_AND_LOAD = """
+import sys
+from geomesa_tpu import native
+ref = getattr(native, sys.argv[1])
+lib = native._NativeLib(sys.argv[2], sys.argv[3], ref.symbol, ref.restype,
+                        ref.argtypes)
+sys.exit(0 if lib.load() is not None else 1)
+"""
+
+
+@pytest.mark.parametrize(
+    "lib", ["_ZRANGES", "_XZRANGES", "_SEEKSCAN", "_BITDECODE"]
+)
+def test_native_build_is_race_free(lib, tmp_path):
+    """Processes that build one library at once all load it: each compiles
+    to a temp file of its own and publishes it atomically, and a process
+    whose build loses still loads the current library a peer published."""
+    import shutil
+    import subprocess
+    import sys
+
+    import geomesa_tpu.native as native
+
+    ref = getattr(native, lib)
+    src = str(tmp_path / os.path.basename(ref.src))
+    shutil.copy(ref.src, src)
+    so = str(tmp_path / "_race.so")
+    env = {k: v for k, v in os.environ.items() if k != "GEOMESA_TPU_NO_NATIVE"}
+    root = os.path.dirname(os.path.dirname(os.path.dirname(native.__file__)))
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (root, env.get("PYTHONPATH")) if p
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", _BUILD_AND_LOAD, lib, src, so],
+            env=env,
+            stdout=subprocess.PIPE,
+            stderr=subprocess.STDOUT,
+        )
+        for _ in range(6)
+    ]
+    outs = [p.communicate(timeout=300)[0] for p in procs]
+    assert [p.returncode for p in procs] == [0] * 6, outs
+    assert not list(tmp_path.glob("*.tmp"))
+    assert len(list(tmp_path.glob("_race.*.so"))) == 1

@@ -8,6 +8,7 @@ Reference role: the join attribute strategy evaluated at the data
 (AttributeIndex.scala:42,392) extended to extent schemas.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -55,9 +56,11 @@ def _rows(n, seed, null_every=13):
     return rows
 
 
-def _stores(n=8000, seed=51, batches=2):
+def _stores(n=8000, seed=51, batches=2, devices=None):
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     rows = _rows(n, seed)
     for s in (host, tpu):
         s.create_schema(parse_spec("e", SPEC))
@@ -95,10 +98,9 @@ BOX2 = "bbox(geom, -80, -50, 60, 45)"
 WIN = "dtg DURING 2026-01-02T00:00:00Z/2026-01-10T00:00:00Z"
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_xz_attr_member_parity(monkeypatch, proto):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+@pytest.mark.parametrize("devices", [1, 8])
+def test_xz_attr_member_parity(devices):
+    host, tpu = _stores(devices=devices)
     got = _parity(host, tpu, [
         f"kind = 'c2' AND {BOX}",
         f"kind = 'c4' AND {BOX2}",
@@ -109,10 +111,9 @@ def test_xz_attr_member_parity(monkeypatch, proto):
     _plane_loaded(tpu, "kind")
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_xz_attr_range_parity(monkeypatch, proto):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _stores()
+@pytest.mark.parametrize("devices", [1, 8])
+def test_xz_attr_range_parity(devices):
+    host, tpu = _stores(devices=devices)
     _parity(host, tpu, [
         f"size > 2.5 AND size <= 7.0 AND {BOX}",
         f"size BETWEEN 1.0 AND 4.0 AND {BOX2}",
@@ -126,9 +127,8 @@ def test_xz_attr_range_parity(monkeypatch, proto):
     _plane_loaded(tpu, "kind")
 
 
-def test_xz3_attr_with_window(monkeypatch):
+def test_xz3_attr_with_window():
     """xz3 edition: spatial AND window AND attr all decided on device."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores()
     _parity(host, tpu, [
         f"kind = 'c1' AND {BOX} AND {WIN}",
@@ -140,10 +140,8 @@ def test_xz3_attr_with_window(monkeypatch):
     _plane_loaded(tpu, "size")
 
 
-def test_xz_attr_shard_extract(monkeypatch):
+def test_xz_attr_shard_extract():
     """Per-shard dual-window extraction with the attr plane fused in."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
-    monkeypatch.setenv("GEOMESA_SHARD_EXTRACT", "1")
     host, tpu = _stores()
     # every (table, has_time, attr, kind) group needs >= 2 members or
     # the lone query routes to the host single path
@@ -159,11 +157,10 @@ def test_xz_attr_shard_extract(monkeypatch):
     _plane_loaded(tpu, "size")
 
 
-def test_xz_attr_nongeometry_predicates_on_intersects(monkeypatch):
+def test_xz_attr_nongeometry_predicates_on_intersects():
     """Non-rect INTERSECTS query geometry + attr preds: decided stays
     empty (rect flag off) and the whole ring takes the host geometry
     test — attr already excluded non-matching rows from the ring."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
     host, tpu = _stores(n=5000)
     tri = "POLYGON ((-35 -25, 25 -20, 0 22, -35 -25))"
     tri2 = "POLYGON ((-60 -35, 10 -40, -20 15, -60 -35))"
@@ -198,7 +195,7 @@ from geomesa_tpu.geom.base import Point  # noqa: E402
 PT_SPEC = "dtg:Date,kind:String,score:Int,*geom:Point:srid=4326"
 
 
-def _point_stores(n=20_000, seed=61):
+def _point_stores(n=20_000, seed=61, devices=None):
     rng = np.random.default_rng(seed)
     # rows precomputed ONCE — generating inside the store loop would give
     # host and tpu different data (the rng state advances)
@@ -213,7 +210,9 @@ def _point_stores(n=20_000, seed=61):
         for i in range(n)
     ]
     host = TpuDataStore(executor=HostScanExecutor())
-    tpu = TpuDataStore(executor=TpuScanExecutor(default_mesh()))
+    tpu = TpuDataStore(
+        executor=TpuScanExecutor(default_mesh(jax.devices()[:devices]))
+    )
     for s in (host, tpu):
         s.create_schema(parse_spec("p", PT_SPEC))
         with s.writer("p") as w:
@@ -234,13 +233,12 @@ TRI = "POLYGON ((-40 -40, 30 -35, 10 30, -35 20, -40 -40))"
 TRI2 = "POLYGON ((-15 -50, 50 -40, 25 15, -15 -50))"
 
 
-@pytest.mark.parametrize("proto", ["bitmap", "runs_packed"])
-def test_poly_attr_member_and_range(monkeypatch, proto):
+@pytest.mark.parametrize("devices", [1, 8])
+def test_poly_attr_member_and_range(devices):
     """Attr plane fused into the banded ray-cast batches: the band ring
     only carries attr-passing rows; decided rows are final for the full
     polygon-AND-attr predicate."""
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", proto)
-    host, tpu = _point_stores()
+    host, tpu = _point_stores(devices=devices)
     got = _pparity(host, tpu, [
         f"kind = 'k1' AND intersects(geom, {TRI})",
         f"kind = 'k3' AND intersects(geom, {TRI2})",
@@ -256,9 +254,7 @@ def test_poly_attr_member_and_range(monkeypatch, proto):
     )
 
 
-def test_poly_attr_with_window_and_shard_extract(monkeypatch):
-    monkeypatch.setenv("GEOMESA_BATCH_PROTO", "bitmap")
-    monkeypatch.setenv("GEOMESA_SHARD_EXTRACT", "1")
+def test_poly_attr_with_window_and_shard_extract():
     host, tpu = _point_stores()
     _pparity(host, tpu, [
         f"kind = 'k0' AND intersects(geom, {TRI}) AND "
